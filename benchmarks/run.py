@@ -18,6 +18,7 @@ from benchmarks import (adaptive_split, cloud_batching, collab_throughput,
 from benchmarks.common import (write_collab_record, write_energy_record,
                                write_faults_record, write_fleet_record,
                                write_kernels_record)
+from repro import compile_cache
 
 BENCHES = [
     ("table2_split_latency", table2_split_latency.run),
@@ -37,6 +38,7 @@ BENCHES = [
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="reduced sizes/epochs for CI-style runs")

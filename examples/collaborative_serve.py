@@ -29,7 +29,7 @@ import time
 import jax
 import numpy as np
 
-from repro import serving
+from repro import compile_cache, serving
 from repro.core.collab.protocol import CODEC_TX_SCALE
 from repro.core.partition.profiles import (LinkProfile, PAPER_PROFILE,
                                            TRACES, TwoTierProfile)
@@ -65,6 +65,7 @@ def build_plan(args) -> serving.DeploymentPlan:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--bandwidth-mbps", type=float, default=50.0)

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import serving
+from repro import compile_cache, serving
 
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.core.partition.latency_model import transformer_layer_costs
@@ -29,6 +29,7 @@ from repro.models import transformer as tr
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="mixtral-8x7b")
     ap.add_argument("--episodes", type=int, default=8)

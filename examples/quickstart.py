@@ -12,13 +12,14 @@
 """
 import numpy as np
 
-from repro import serving
+from repro import compile_cache, serving
 from repro.core.pipeline import run_paper_pipeline
 from repro.data.synthetic import PlantVillageSynthetic
 from repro.models.cnn import tiny_cnn_config
 
 
 def main():
+    compile_cache.enable()
     print("== quickstart: prune + split a plant-disease CNN ==")
     cfg = tiny_cnn_config(num_classes=38, width=0.25, hw=32)
     data = PlantVillageSynthetic(n_per_class=10, hw=32)

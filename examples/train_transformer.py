@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.checkpoint import store
 from repro.configs.registry import ARCH_IDS, get_smoke_config
 from repro.launch.mesh import make_host_mesh
@@ -45,6 +46,7 @@ def synth_batch(cfg, key, B, S):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2-7b")
     ap.add_argument("--steps", type=int, default=100)

@@ -64,7 +64,6 @@ class ClosureVar:
 CONCURRENCY: Dict[str, Tuple] = {
     "core/collab/runtime.py": (
         SharedAttr("SplitFnBank", "_fns", lock="_cache_lock"),
-        SharedAttr("SplitFnBank", "_batched_fns", lock="_cache_lock"),
         SharedAttr("SplitFnBank", "n_traces", lock=None,
                    note="approximate diagnostic counter bumped inside "
                         "jax-traced closures; a lock cannot wrap a traced "

@@ -60,6 +60,7 @@ payload, and is excluded.
 """
 from __future__ import annotations
 
+import functools
 import queue
 import socket
 import struct
@@ -147,6 +148,45 @@ def deploy_submodels(params, cfg: CNNConfig, masks=None,
     return params, cfg, masks
 
 
+def half_params(params: Dict, split: int, side: str) -> Dict:
+    """The layers one half of ``split`` reads: ``[0, split)`` on the
+    ``"edge"``, ``[split, N)`` in the ``"cloud"``."""
+    edge = side == "edge"
+    return {k: v for k, v in params.items() if (int(k[1:]) < split) == edge}
+
+
+def split_step(cfg: CNNConfig, split: int, side: str, masks=None,
+               quant_backend: Optional[Tuple[str, bool]] = None,
+               batched: bool = False) -> Callable:
+    """The pure ``(params, x) -> y`` step of one half of ``split`` over
+    ``half_params``: the edge runs layers ``[0, split)`` — through the
+    masked-GEMM kernel path over quantized params when
+    ``quant_backend`` (``resolve_backend``'s pair) is given — and the
+    cloud runs ``[split, N)`` fp32 dense. ``batched`` maps the batch-1
+    computation over the leading axis (``jax.lax.map``) so each row is
+    bit-identical to the batch-1 step — which a free reshape-to-batched
+    conv would NOT guarantee (XLA may re-associate reductions under a
+    different batch shape) — and the dynamic batching engine can
+    promise batched == sequential logits exactly."""
+    if quant_backend is not None:
+        from repro.core.collab.quant import quant_cnn_apply
+        backend, interpret = quant_backend
+
+        def run(p, x):
+            return quant_cnn_apply(p, cfg, x, masks=masks, stop_layer=split,
+                                   backend=backend, interpret=interpret)
+    elif side == "edge":
+        def run(p, x):
+            return cnn_apply(p, cfg, x, masks=masks, stop_layer=split)
+    else:
+        def run(p, x):
+            return cnn_apply(p, cfg, x, masks=masks, start_layer=split)
+
+    if not batched:
+        return run
+    return lambda p, x: jax.lax.map(lambda row: run(p, row[None])[0], x)
+
+
 class SplitFnBank:
     """Jitted edge/cloud sub-model functions for *every* candidate split
     of one deployed network.
@@ -157,30 +197,37 @@ class SplitFnBank:
     dictionary lookup instead of a redeploy. Both peers of a socket
     deployment hold a bank over the same params, which is what makes the
     RESPLIT frame sufficient to move the partition without reconnecting.
+
+    The deployed (and quantized) params are placed on the device once and
+    reach every executable as *arguments* (``split_step`` bound to
+    ``half_params``): a closed-over array would be lowered as an HLO
+    constant, copying the weights into each (split, bucket) executable
+    and its compile time.
     """
 
     def __init__(self, params, cfg: CNNConfig, masks=None,
                  compact: bool = False, pack: bool = False, quant=None):
-        (self.dparams, self.deploy_cfg,
+        (dparams, self.deploy_cfg,
          self.dmasks) = deploy_submodels(params, cfg, masks, compact)
+        self.dparams = jax.device_put(dparams)
         self.pack = pack
         self.compact = compact
-        #: optional ``QuantPolicy`` — when set, the EDGE closure of every
+        #: optional ``QuantPolicy`` — when set, the EDGE half of every
         #: split dispatches conv/dense through the masked-GEMM kernel
         #: over (possibly int8/int4-quantized) deployed weights; cloud
         #: halves stay fp32 dense (the server is not the device the
         #: paper quantizes for). Resolved eagerly so bank construction
-        #: owns all quant state and the closures stay read-only.
+        #: owns all quant state and the built functions stay read-only.
         self.quant = quant
         if quant is not None:
             from repro.core.collab.quant import (quantize_params,
                                                  resolve_backend)
-            self._qparams = quantize_params(self.dparams, self.deploy_cfg,
-                                            quant)
-            self._q_backend, self._q_interpret = resolve_backend(quant)
+            self._qparams = jax.device_put(quantize_params(
+                self.dparams, self.deploy_cfg, quant))
+            self._q_backend = resolve_backend(quant)
         self.n_layers = len(self.deploy_cfg.layers)
-        self._fns: Dict[int, Tuple] = {}
-        self._batched_fns: Dict[int, Tuple] = {}
+        #: (split, batched) -> (edge_fn, cloud_fn, keep)
+        self._fns: Dict[Tuple[int, bool], Tuple] = {}
         # serve_cloud handler threads share one bank: first-touch builds
         # of a (split, bucket) pair must not race the dict insert
         self._cache_lock = threading.Lock()
@@ -190,71 +237,24 @@ class SplitFnBank:
         #: no-recompilation-in-steady-state regression guard.
         self.n_traces = 0
 
-    def _build(self, split: int) -> Tuple:
-        dparams, dcfg, dmasks = self.dparams, self.deploy_cfg, self.dmasks
+    def _build(self, split: int, batched: bool) -> Tuple:
+        def bind(side: str):
+            quantized = side == "edge" and self.quant is not None
+            step = split_step(self.deploy_cfg, split, side, self.dmasks,
+                              self._q_backend if quantized else None,
+                              batched)
 
-        if self.quant is not None:
-            from repro.core.collab.quant import quant_cnn_apply
-            qp, qb, qi = self._qparams, self._q_backend, self._q_interpret
-
-            def _edge(x):
+            def traced(p, x):
                 self.n_traces += 1      # runs at trace time only
-                return quant_cnn_apply(qp, dcfg, x, masks=dmasks,
-                                       stop_layer=split, backend=qb,
-                                       interpret=qi)
-        else:
-            def _edge(x):
-                self.n_traces += 1      # runs at trace time only
-                return cnn_apply(dparams, dcfg, x, masks=dmasks,
-                                 stop_layer=split)
+                return step(p, x)
 
-        def _cloud(x):
-            self.n_traces += 1          # runs at trace time only
-            return cnn_apply(dparams, dcfg, jnp.asarray(x), masks=dmasks,
-                             start_layer=split)
+            src = self._qparams if quantized else self.dparams
+            return functools.partial(jax.jit(traced),
+                                     half_params(src, split, side))
 
-        edge_fn = jax.jit(_edge) if split > 0 else None
-        cloud_fn = jax.jit(_cloud) if split < self.n_layers else None
-        keep = (split_keep_indices(dcfg, dmasks, split)
-                if self.pack and not self.compact else None)
-        return edge_fn, cloud_fn, keep
-
-    def _build_batched(self, split: int) -> Tuple:
-        """Row-mapped variants: the batch-1 computation mapped over the
-        leading axis (``jax.lax.map``) in ONE jitted call. Per-row results
-        are bit-identical to the batch-1 functions — which a free
-        reshape-to-batched conv would NOT guarantee (XLA may re-associate
-        reductions under a different batch shape) — so the dynamic
-        batching engine can promise batched == sequential logits exactly.
-        """
-        dparams, dcfg, dmasks = self.dparams, self.deploy_cfg, self.dmasks
-
-        if self.quant is not None:
-            from repro.core.collab.quant import quant_cnn_apply
-            qp, qb, qi = self._qparams, self._q_backend, self._q_interpret
-
-            def _edge_row(row):
-                self.n_traces += 1      # runs at trace time only
-                return quant_cnn_apply(qp, dcfg, row[None], masks=dmasks,
-                                       stop_layer=split, backend=qb,
-                                       interpret=qi)[0]
-        else:
-            def _edge_row(row):
-                self.n_traces += 1      # runs at trace time only
-                return cnn_apply(dparams, dcfg, row[None], masks=dmasks,
-                                 stop_layer=split)[0]
-
-        def _cloud_row(row):
-            self.n_traces += 1          # runs at trace time only
-            return cnn_apply(dparams, dcfg, row[None], masks=dmasks,
-                             start_layer=split)[0]
-
-        edge_fn = (jax.jit(lambda x: jax.lax.map(_edge_row, x))
-                   if split > 0 else None)
-        cloud_fn = (jax.jit(lambda x: jax.lax.map(_cloud_row,
-                                                  jnp.asarray(x)))
-                    if split < self.n_layers else None)
-        keep = (split_keep_indices(dcfg, dmasks, split)
+        edge_fn = bind("edge") if split > 0 else None
+        cloud_fn = bind("cloud") if split < self.n_layers else None
+        keep = (split_keep_indices(self.deploy_cfg, self.dmasks, split)
                 if self.pack and not self.compact else None)
         return edge_fn, cloud_fn, keep
 
@@ -272,17 +272,13 @@ class SplitFnBank:
         the batch-1 pair. ``None`` keeps the historical batch-1 pair."""
         if not 0 <= split <= self.n_layers:
             raise ValueError(f"split {split} outside [0, {self.n_layers}]")
-        if batch_bucket is None:
-            with self._cache_lock:
-                if split not in self._fns:
-                    self._fns[split] = self._build(split)
-                return self._fns[split]
-        if batch_bucket < 1:
+        if batch_bucket is not None and batch_bucket < 1:
             raise ValueError(f"batch_bucket must be >= 1, got {batch_bucket}")
+        key = (split, batch_bucket is not None)
         with self._cache_lock:
-            if split not in self._batched_fns:
-                self._batched_fns[split] = self._build_batched(split)
-            return self._batched_fns[split]
+            if key not in self._fns:
+                self._fns[key] = self._build(*key)
+            return self._fns[key]
 
     def warm(self, splits: Sequence[int], image: np.ndarray,
              edge_only: bool = False, buckets: Sequence[int] = (1,),

@@ -24,7 +24,6 @@ layer-range executor) and num_layers % n_pods == 0.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -33,28 +32,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tr
-
-
-def _shard_map_pod_manual(f, mesh, in_specs, out_specs):
-    """shard_map with only the "pod" axis manual, across jax versions:
-    new API spells it axis_names={"pod"}/check_vma, jax 0.4.x spells the
-    complement auto=<other axes>/check_rep — and 0.4.x can't report manual
-    axes to ``maybe_constrain``, so the body declares them explicitly."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names={"pod"},
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    from repro.sharding.constraints import declared_manual_axes
-
-    @functools.wraps(f)
-    def body(*args):
-        with declared_manual_axes("pod"):
-            return f(*args)
-
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False,
-                     auto=frozenset(mesh.axis_names) - {"pod"})
 
 
 def pipeline_supported(cfg: ModelConfig) -> bool:
@@ -162,10 +139,9 @@ def make_pipeline_forward(cfg: ModelConfig, n_pods: int,
                       jnp.zeros(y.shape, jnp.float32)), "pod")
         return y.astype(x.dtype)
 
-    return _shard_map_pod_manual(
-        pipelined, mesh,
-        in_specs=(P("pod"), P(), P()),
-        out_specs=P())
+    return jax.shard_map(pipelined, mesh=mesh, axis_names={"pod"},
+                         in_specs=(P("pod"), P(), P()), out_specs=P(),
+                         check_vma=False)
 
 
 def make_split_serve_step(cfg: ModelConfig, n_pods: int,

@@ -15,8 +15,6 @@ guarantee that downstream layers see exact zeros.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -37,8 +35,8 @@ def _mm_kernel(a_ref, b_ref, m_ref, o_ref, acc_ref):
 
     @pl.when(ki == nk - 1)
     def _done():
-        mask = m_ref[...].astype(jnp.float32)          # (block_n,)
-        o_ref[...] = (acc_ref[...] * mask[None, :]).astype(o_ref.dtype)
+        mask = m_ref[...].astype(jnp.float32)          # (1, block_n)
+        o_ref[...] = (acc_ref[...] * mask).astype(o_ref.dtype)
 
 
 def masked_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray,
@@ -47,7 +45,11 @@ def masked_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray,
                          block_k: int = 128,
                          interpret: bool = False) -> jnp.ndarray:
     """a (M, K) @ b (K, N) with output-column mask (N,). Dims must divide
-    by their blocks (ops.py pads)."""
+    by their blocks (ops.py pads).
+
+    The mask enters the kernel as a ``(1, N)`` row with ``(1, block_n)``
+    blocks: a 1-D operand padded past one block is tiled differently by
+    XLA and by Mosaic, which refuses the layout mismatch."""
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 and col_mask.shape == (N,)
@@ -59,11 +61,11 @@ def masked_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((block_n,), lambda mi, ni, ki: (ni,)),
+            pl.BlockSpec((1, block_n), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(a, b, col_mask)
+    )(a, b, col_mask.reshape(1, N))

@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, hm_ref,
                 y_ref, fs_ref, state_ref, *, chunk: int):
@@ -119,7 +115,7 @@ def ssd_scan_pallas(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, dt, A, Bm, Cm, head_mask)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Multi-pod dry-run: lower + compile every (architecture x input shape) on
 # the production meshes, with zero real allocation (ShapeDtypeStruct inputs).
@@ -13,8 +14,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # roofline terms. Failures (sharding mismatch, OOM at compile, unsupported
 # collective) are bugs in the system — the matrix must be green.
 #
-# NOTE: the two os lines above MUST stay the first statements — jax locks
-# the device count on first init.
+# NOTE: the os lines above MUST stay the first statements — jax locks
+# the device count on first init. The dry run compiles on 512 host
+# devices, so it pins itself to the CPU backend and never takes a chip.
 
 import argparse
 import json

@@ -16,6 +16,10 @@ import subprocess
 import sys
 import time
 
+# a host-device compile sweep: neither this process nor its children (which
+# inherit the variable) may take an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch.specs import SHAPES, mode_of, supported
 

@@ -502,7 +502,6 @@ class CloudServer:
                  port: Optional[int] = None, host: Optional[str] = None,
                  max_requests: Optional[int] = None,
                  max_clients: Optional[int] = None, verify: bool = True,
-                 start_timeout: float = 10.0,
                  trace: Optional[LinkTrace] = None,
                  simulate_server=None,
                  faults: Optional[FaultInjector] = None):
@@ -516,19 +515,31 @@ class CloudServer:
         self._die = threading.Event()
         self._drain = threading.Event()
         ready = threading.Event()
-        self._thread = threading.Thread(
-            target=serve, args=(plan,),
-            kwargs=dict(port=port, host=host, max_requests=max_requests,
-                        max_clients=max_clients, ready=ready,
-                        stop=self._stop, verify=verify, trace=trace,
-                        batch_stats=self.batch_stats,
-                        simulate_server=simulate_server, faults=faults,
-                        fault_stats=self.fault_stats, die=self._die,
-                        drain=self._drain),
-            daemon=True)
+        failed: List[Exception] = []
+
+        def _serve() -> None:
+            try:
+                serve(plan, port=port, host=host, max_requests=max_requests,
+                      max_clients=max_clients, ready=ready,
+                      stop=self._stop, verify=verify, trace=trace,
+                      batch_stats=self.batch_stats,
+                      simulate_server=simulate_server, faults=faults,
+                      fault_stats=self.fault_stats, die=self._die,
+                      drain=self._drain)
+            except Exception as e:                   # noqa: BLE001
+                failed.append(e)         # re-raised below, as the cause
+
+        self._thread = threading.Thread(target=_serve, daemon=True)
         self._thread.start()
-        if not ready.wait(start_timeout):
-            raise TimeoutError("cloud server failed to start listening")
+        # the serve thread compiles every (split, bucket) executable
+        # before it listens — minutes on a cold cache — so the wait
+        # lasts as long as the thread does, and ends only in a listener
+        # or in the thread's own error
+        while not ready.wait(0.1):
+            if not self._thread.is_alive():
+                raise RuntimeError(
+                    "cloud server exited before listening"
+                ) from (failed[0] if failed else None)
 
     def stop(self, timeout: float = 10.0) -> None:
         """Signal the serve loop to quit and join its thread (seconds):
@@ -591,7 +602,7 @@ class CloudFleet:
 
     def __init__(self, plan: DeploymentPlan, *, verify: bool = True,
                  max_clients: Optional[int] = None,
-                 simulate_server=None, start_timeout: float = 10.0):
+                 simulate_server=None):
         if plan.routing is None or not plan.routing.ports:
             raise ValueError(
                 "CloudFleet needs a plan with a routing section "
@@ -600,7 +611,6 @@ class CloudFleet:
         self._verify = verify
         self._max_clients = max_clients
         self._simulate_server = simulate_server
-        self._start_timeout = start_timeout
         self._lock = threading.Lock()
         self._servers: Dict[int, CloudServer] = {}
         for p in plan.routing.ports:
@@ -609,8 +619,7 @@ class CloudFleet:
     def _spawn(self, port: int) -> CloudServer:
         return CloudServer(
             self.plan, port=port, max_clients=self._max_clients,
-            verify=self._verify, simulate_server=self._simulate_server,
-            start_timeout=self._start_timeout)
+            verify=self._verify, simulate_server=self._simulate_server)
 
     @property
     def ports(self) -> tuple:

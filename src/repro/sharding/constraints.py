@@ -7,49 +7,28 @@ code can state its preferred layout unconditionally. Uneven dims are allowed
 """
 from __future__ import annotations
 
-import contextlib
-import threading
-
 import jax
 import numpy as np
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-# jax versions without mesh axis_types (<= 0.4.x) can't tell us which axes
-# a surrounding shard_map made manual; the shard_map entry points in this
-# repo declare them here instead (trace-time, thread-local).
-_manual = threading.local()
 
-
-@contextlib.contextmanager
-def declared_manual_axes(*names):
-    old = getattr(_manual, "axes", ())
-    _manual.axes = old + tuple(names)
-    try:
-        yield
-    finally:
-        _manual.axes = old
+def _auto_axes(mesh) -> tuple:
+    return tuple(n for n, t in zip(mesh.axis_names, mesh.axis_types)
+                 if t == AxisType.Auto)
 
 
 def _current_axes():
-    declared = getattr(_manual, "axes", ())
-    # explicit-sharding mode / inside shard_map: only AUTO axes are
-    # constrainable (manual axes belong to the shard_map body)
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return tuple(n for n, t in zip(m.axis_names, m.axis_types)
-                         if str(t) == "Auto" and n not in declared)
-    except Exception:                                     # noqa: BLE001
-        pass
-    # classic `with mesh:` context (auto axes)
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return tuple(n for n in pm.axis_names if n not in declared)
-    except Exception:                                     # noqa: BLE001
-        pass
-    return ()
+    """The constrainable (``AxisType.Auto``) axes of the surrounding mesh:
+    Explicit axes are sharded by their types, and the Manual axes of a
+    shard_map belong to its body."""
+    m = jax.sharding.get_abstract_mesh()
+    if not m.empty:
+        return _auto_axes(m)
+    # classic `with mesh:` context
+    from jax._src.mesh import thread_resources
+    pm = thread_resources.env.physical_mesh
+    return () if pm.empty else _auto_axes(pm)
 
 
 def maybe_constrain(x, spec: P):

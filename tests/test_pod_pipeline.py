@@ -48,7 +48,9 @@ _SUBPROC = textwrap.dedent("""
     from repro.models import transformer as tr
     from repro.core.partition import pod_pipeline as pp
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     for arch in ["qwen2-7b", "mamba2-2.7b", "mixtral-8x7b"]:
         cfg = get_smoke_config(arch).replace(dtype="float32", remat=False)
         if cfg.moe:
@@ -73,10 +75,6 @@ _SUBPROC = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_two_pod_pipeline_matches_forward_subprocess():
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("multi-pod partial-auto shard_map needs jax >= 0.5 "
-                    "(0.4.x lowers axis_index under auto axes to a "
-                    "PartitionId op the SPMD partitioner rejects)")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _SUBPROC], cwd=os.path.join(

@@ -190,6 +190,20 @@ def test_serve_cloud_survives_connect_and_drop(plan_setup):
             assert sess.infer(plan_setup[3])["tx_bytes"] > 0
 
 
+def test_cloud_server_start_surfaces_serve_thread_error(plan_setup):
+    """Startup waits for the listener as long as the serve thread lives
+    (a cold warm-up may compile for minutes), and a thread that dies
+    first is reported with its own error, not a timeout."""
+    import socket as socketlib
+    with socketlib.socket() as taken:
+        taken.bind(("127.0.0.1", 0))
+        taken.listen(1)
+        plan = make_plan(plan_setup, port=taken.getsockname()[1])
+        with pytest.raises(RuntimeError, match="before listening") as err:
+            serving.CloudServer(plan)
+    assert isinstance(err.value.__cause__, OSError)
+
+
 def test_handshake_skipped_for_legacy_edge(plan_setup):
     """An edge that never sends HELLO (verify=False) is served unchecked —
     back-compat with pre-plan clients."""
