@@ -83,6 +83,8 @@ CONCURRENCY: Dict[str, Tuple] = {
                    note="single lane-scheduler-thread owner (see rows)"),
         SharedAttr("LaneStats", "busy_s", lock=None,
                    note="single lane-scheduler-thread owner (see rows)"),
+        SharedAttr("LaneStats", "queue_wait_s", lock=None,
+                   note="single lane-scheduler-thread owner (see rows)"),
         SharedAttr("LaneStats", "failed_rows", lock=None,
                    note="single lane-scheduler-thread owner (see rows)"),
         SharedAttr("LaneStats", "cancelled_frames", lock=None,
@@ -134,6 +136,10 @@ CONCURRENCY: Dict[str, Tuple] = {
     ),
     "serving/session.py": (
         SharedAttr("CloudFleet", "_servers", lock="_lock"),
+        SharedAttr("CloudServer", "_engine", lock=None,
+                   note="written once by the serve thread before it sets "
+                        "the ready event that __init__ waits on; read-only "
+                        "after that"),
     ),
 }
 

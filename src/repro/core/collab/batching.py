@@ -47,6 +47,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -178,6 +179,7 @@ class LaneStats:
     batches: int = 0              # cloud calls
     padded_rows: int = 0          # zero rows added to reach the bucket
     busy_s: float = 0.0           # wall time inside the jitted cloud call
+    queue_wait_s: float = 0.0     # summed over frames: submit to call start
     failed_rows: int = 0          # rows whose future resolved to an error
     cancelled_frames: int = 0     # frames cancelled at drain/stop
     batch_sizes: "deque" = field(
@@ -195,10 +197,13 @@ class LaneStats:
 
     def to_json(self) -> Dict[str, Any]:
         """JSON-ready per-lane record (rows/frames/batches counts,
-        ``busy_s`` seconds inside the jitted call, padding waste)."""
+        ``busy_s`` seconds inside the jitted call, ``queue_wait_s``
+        seconds frames waited from ``submit`` to their call, padding
+        waste)."""
         return {"lane": list(map(str, self.lane)), "rows": self.rows,
                 "frames": self.frames, "batches": self.batches,
                 "padded_rows": self.padded_rows, "busy_s": self.busy_s,
+                "queue_wait_s": self.queue_wait_s,
                 "failed_rows": self.failed_rows,
                 "cancelled_frames": self.cancelled_frames,
                 "avg_batch": self.avg_batch,
@@ -280,13 +285,15 @@ class DynamicBatcher:
             raise LaneSaturated(
                 f"lane {key} is {self.policy.max_queue} frames deep")
         fut: Future = Future()
-        ln.q.put((x, rows, fut))
+        ln.q.put((x, rows, fut, time.perf_counter()))
         return fut
 
     # -- scheduler ----------------------------------------------------------
-    def _collect(self, ln: _Lane) -> List[Tuple[np.ndarray, int, Future]]:
+    def _collect(self, ln: _Lane
+                 ) -> List[Tuple[np.ndarray, int, Future, float]]:
         """Block for the first request, then top the batch up (by rows)
-        within the ``max_wait_ms`` window."""
+        within the ``max_wait_ms`` window: the ``repro.batcher.fill``
+        span, which holds the window's wait by design."""
         while True:
             if ln.carry is not None:
                 first, ln.carry = ln.carry, None
@@ -302,24 +309,28 @@ class DynamicBatcher:
             batch = [first]
             rows = first[1]
             deadline = time.monotonic() + self.policy.max_wait_ms / 1e3
-            while rows < self.policy.max_batch:
-                left = deadline - time.monotonic()
-                try:
-                    nxt = (ln.q.get_nowait() if left <= 0
-                           else ln.q.get(timeout=left))
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    ln.q.put(None)      # re-post for the outer loop
-                    break
-                if rows + nxt[1] > self.policy.max_batch:
-                    # doesn't fit this bucket: hold it — it OPENS the next
-                    # batch (re-queueing at the tail would let a steady
-                    # stream of small frames starve a wide one forever)
-                    ln.carry = nxt
-                    break
-                batch.append(nxt)
-                rows += nxt[1]
+            fill = jax.profiler.TraceAnnotation("repro.batcher.fill")
+            with fill:
+                while rows < self.policy.max_batch:
+                    left = deadline - time.monotonic()
+                    try:
+                        nxt = (ln.q.get_nowait() if left <= 0
+                               else ln.q.get(timeout=left))
+                    except queue.Empty:
+                        break
+                    if nxt is None:
+                        ln.q.put(None)      # re-post for the outer loop
+                        break
+                    if rows + nxt[1] > self.policy.max_batch:
+                        # doesn't fit this bucket: hold it — it OPENS the
+                        # next batch (re-queueing at the tail would let a
+                        # steady stream of small frames starve a wide one
+                        # forever)
+                        ln.carry = nxt
+                        break
+                    batch.append(nxt)
+                    rows += nxt[1]
+                fill.set_metadata(rows=rows)
             return batch
 
     def _scheduler(self, ln: _Lane) -> None:
@@ -345,7 +356,7 @@ class DynamicBatcher:
                 break
             if item is not None:
                 items.append(item)
-        for _, n, fut in items:
+        for _, n, fut, _ in items:
             if not fut.done():
                 fut.set_exception(exc)
                 ln.stats.failed_rows += n
@@ -362,13 +373,16 @@ class DynamicBatcher:
                 xs = pad_rows(np.concatenate([b[0] for b in batch],
                                              axis=0), bucket)
                 _, cloud_fn, _ = self.bank.get(split, batch_bucket=bucket)
-                t0 = time.perf_counter()
-                out = np.asarray(cloud_fn(jnp.asarray(xs)))
-                if self.invoke_cost is not None:
-                    self.invoke_cost(split, bucket)
-                dt = time.perf_counter() - t0
+                with jax.profiler.TraceAnnotation(
+                        "repro.cloud.call", split=split, bucket=bucket,
+                        rows=rows):
+                    t0 = time.perf_counter()
+                    out = np.asarray(cloud_fn(jnp.asarray(xs)))
+                    if self.invoke_cost is not None:
+                        self.invoke_cost(split, bucket)
+                    dt = time.perf_counter() - t0
             except Exception as e:                       # noqa: BLE001
-                for _, n, fut in batch:
+                for _, n, fut, _ in batch:
                     if not fut.done():
                         fut.set_exception(e)
                         ln.stats.failed_rows += n
@@ -379,9 +393,10 @@ class DynamicBatcher:
             st.batches += 1
             st.padded_rows += bucket - rows
             st.busy_s += dt
+            st.queue_wait_s += sum(t0 - b[3] for b in batch)
             st.batch_sizes.append(rows)
             off = 0
-            for _, n, fut in batch:
+            for _, n, fut, _ in batch:
                 fut.set_result(out[off:off + n])
                 off += n
 

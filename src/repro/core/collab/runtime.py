@@ -57,6 +57,15 @@ estimation + hysteresis) lives in ``repro.core.collab.adaptive``.
 across CollabRunner, EdgeClient, and the streaming runtime for the same
 deployment; the socket executors' 8-byte length prefix is framing, not
 payload, and is excluded.
+
+*Host spans* of the socket path (``jax.profiler.TraceAnnotation``, on the
+same clock as the device's operations while a profiler trace runs, one
+cheap check each otherwise): ``repro.edge.forward``, ``repro.edge.encode``
+and ``repro.edge.decode`` on the edge, ``repro.cloud.decode`` and
+``repro.cloud.encode`` in the server, and the batcher's
+``repro.batcher.fill`` and ``repro.cloud.call``. None wraps a blocking
+socket read or the wait for a queued item, except that ``fill`` holds the
+batch window by design.
 """
 from __future__ import annotations
 
@@ -248,6 +257,10 @@ class SplitFnBank:
                 self.n_traces += 1      # runs at trace time only
                 return step(p, x)
 
+            # the program's name in the profiler's module line:
+            # jit_edge_c13, jit_cloud_c13_rows
+            traced.__name__ = traced.__qualname__ = (
+                f"{side}_c{split}" + ("_rows" if batched else ""))
             src = self._qparams if quantized else self.dparams
             return functools.partial(jax.jit(traced),
                                      half_params(src, split, side))
@@ -571,7 +584,9 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
                 fault_stats: Optional[Dict] = None,
                 die: Optional[threading.Event] = None,
                 drain: Optional[threading.Event] = None,
-                quant=None) -> None:
+                quant=None,
+                on_engine: Optional[Callable[[DynamicBatcher], None]] = None
+                ) -> None:
     """Cloud-side loop: accept edge connections, answer frames.
 
     A threaded accept loop serves each connection in its own handler
@@ -620,7 +635,8 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
     frame's rows are computed as batch-1 rows, not one fused conv, and a
     frame wider than ``max_batch`` bypasses the engine). ``batch_stats``
     (a dict) receives the engine's per-lane accounting when the server
-    shuts down.
+    shuts down; ``on_engine`` is called with the engine once it is built,
+    before the server listens, so a caller can read its live counters.
 
     ``simulate_server`` (a ``ComputeProfile``) charges every cloud
     invocation the analytic ``batched_server_time`` on that hardware,
@@ -678,6 +694,8 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
 
     engine = (DynamicBatcher(bank, batching, invoke_cost=charge)
               if batching else None)
+    if engine is not None and on_engine is not None:
+        on_engine(engine)
     warm_splits = list(resplit_candidates or ())
     if batching and split not in warm_splits:
         warm_splits.append(split)
@@ -747,10 +765,13 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
                         tx(struct.pack("<Q", len(item[1])) + item[1])
                         continue
                     _, seq, val = item
-                    payload = (encode_tensor(np.asarray(val.result()))
-                               if isinstance(val, Future) else val)
-                    frame = (encode_sealed(seq, payload)
-                             if seq is not None else payload)
+                    if isinstance(val, Future):
+                        val = np.asarray(val.result())   # waits: no span
+                    with jax.profiler.TraceAnnotation("repro.cloud.encode"):
+                        payload = (encode_tensor(val)
+                                   if isinstance(val, np.ndarray) else val)
+                        frame = (encode_sealed(seq, payload)
+                                 if seq is not None else payload)
                     frame = _inject(frame)
                     if frame is None:
                         continue             # injected drop
@@ -840,7 +861,8 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
                     _respond_ctl(encode_drain())
                     rec["claimed"] = True
                     continue
-                arr, _ = decode_any(buf)
+                with jax.profiler.TraceAnnotation("repro.cloud.decode"):
+                    arr, _ = decode_any(buf)
                 rows = int(np.asarray(arr).shape[0]) if arr.ndim else 1
                 if (engine is not None and cur_split < bank.n_layers
                         and rows <= batching.max_batch):
@@ -1159,14 +1181,26 @@ class EdgeClient:
         self.use_crc = bool(hello_caps(buf) & CAP_CRC)
 
     # -- framing ------------------------------------------------------------
+    def _forward(self, image: np.ndarray):
+        """The edge half of one request, ended by ``block_until_ready``:
+        the interval ``t_edge`` times."""
+        with jax.profiler.TraceAnnotation("repro.edge.forward",
+                                          split=self.split):
+            x = jnp.asarray(image)
+            if self.edge_fn is not None:
+                x = self.edge_fn(x)
+                jax.block_until_ready(x)
+        return x
+
     def _encode_payload(self, x: np.ndarray) -> bytes:
         """Frame payload (excluding the 8-byte length prefix): the prefix
         is transport framing, so reported ``tx_bytes`` stays comparable
         with the in-process executors' payload accounting."""
-        if self.codec is None and self._keep is None:
-            return encode_tensor(x)
-        return encode_feature(x, codec=self.codec or "fp32",
-                              keep=self._keep)
+        with jax.profiler.TraceAnnotation("repro.edge.encode"):
+            if self.codec is None and self._keep is None:
+                return encode_tensor(x)
+            return encode_feature(x, codec=self.codec or "fp32",
+                                  keep=self._keep)
 
     def _send(self, frame: bytes) -> None:
         (self.ch.sendall if self.ch else self.sock.sendall)(frame)
@@ -1213,7 +1247,8 @@ class EdgeClient:
                     rseq, buf = decode_sealed(buf)
                     if seq is not None and rseq != seq:
                         continue        # stale reply from an old attempt
-                logits, _ = decode_tensor(buf)
+                with jax.profiler.TraceAnnotation("repro.edge.decode"):
+                    logits, _ = decode_tensor(buf)
                 return logits
         except socket.timeout as e:
             raise RequestTimeout(
@@ -1308,10 +1343,7 @@ class EdgeClient:
         record ``{faults, retries, migrations, fallback}``."""
         rec = fault_record()
         t0 = time.perf_counter()
-        x = jnp.asarray(image)
-        if self.edge_fn is not None:
-            x = self.edge_fn(x)
-            jax.block_until_ready(x)
+        x = self._forward(image)
         t1 = time.perf_counter()
         payload = self._encode_payload(np.asarray(x))
         self._seq = (self._seq + 1) & 0xFFFFFFFF
@@ -1433,10 +1465,7 @@ class EdgeClient:
             rid, image = item
             try:
                 t0 = time.perf_counter()
-                x = jnp.asarray(image)
-                if self.edge_fn is not None:
-                    x = self.edge_fn(x)
-                    jax.block_until_ready(x)
+                x = self._forward(image)
                 t_edge = time.perf_counter() - t0
                 payload = self._encode_payload(np.asarray(x))
                 self._send_payload(payload)

@@ -72,13 +72,13 @@ edge-only fallback, exactly as for a single-server cloud death.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from repro.core.collab.adaptive import (AdaptiveSplitController,
                                         SplitSwitch)
-from repro.core.collab.batching import bucket_for
+from repro.core.collab.batching import DynamicBatcher, bucket_for
 from repro.core.collab.channel import FaultInjector
 from repro.core.collab.cluster import FleetRouter
 from repro.core.collab.faults import fault_record
@@ -446,7 +446,9 @@ def serve(plan: DeploymentPlan, *, port: Optional[int] = None,
           faults: Optional[FaultInjector] = None,
           fault_stats: Optional[Dict] = None,
           die: Optional[threading.Event] = None,
-          drain: Optional[threading.Event] = None) -> None:
+          drain: Optional[threading.Event] = None,
+          on_engine: Optional[Callable[[DynamicBatcher], None]] = None,
+          ) -> None:
     """Cloud-side entry point: serve ``plan`` on its link endpoint
     (blocking). ``max_clients=None`` + a ``stop`` event serves many edges
     until told to quit; ``verify`` arms the HELLO digest check. An
@@ -455,7 +457,9 @@ def serve(plan: DeploymentPlan, *, port: Optional[int] = None,
     split valid on the deployed network (manual ``resplit``). A plan with
     a ``batching`` section serves through the cross-client dynamic
     batching engine; pass a dict as ``batch_stats`` to receive its
-    per-lane accounting (fill rate, padding waste) on shutdown.
+    per-lane accounting (fill rate, padding waste) on shutdown, or
+    ``on_engine`` to be handed the engine itself before the server
+    listens (``CloudServer.lane_stats`` reads it live).
     ``simulate_server`` (a ``ComputeProfile``) additionally charges each
     cloud invocation its analytic device time on that hardware,
     serialized server-wide (see ``serve_cloud``) — the benchmark knob for
@@ -488,7 +492,7 @@ def serve(plan: DeploymentPlan, *, port: Optional[int] = None,
                 batch_stats=batch_stats, simulate_server=simulate_server,
                 fault_policy=plan.faults, faults=faults,
                 fault_stats=fault_stats, die=die, drain=drain,
-                quant=plan.quant)
+                quant=plan.quant, on_engine=on_engine)
 
 
 class CloudServer:
@@ -511,6 +515,9 @@ class CloudServer:
         self.batch_stats: Dict = {}
         #: classified server-side error counts (filled on shutdown)
         self.fault_stats: Dict = {}
+        #: the batching engine, handed over by the serve thread before
+        #: it listens (None when the plan does not batch)
+        self._engine: Optional[DynamicBatcher] = None
         self._stop = threading.Event()
         self._die = threading.Event()
         self._drain = threading.Event()
@@ -525,7 +532,7 @@ class CloudServer:
                       batch_stats=self.batch_stats,
                       simulate_server=simulate_server, faults=faults,
                       fault_stats=self.fault_stats, die=self._die,
-                      drain=self._drain)
+                      drain=self._drain, on_engine=self._adopt_engine)
             except Exception as e:                   # noqa: BLE001
                 failed.append(e)         # re-raised below, as the cause
 
@@ -540,6 +547,17 @@ class CloudServer:
                 raise RuntimeError(
                     "cloud server exited before listening"
                 ) from (failed[0] if failed else None)
+
+    def _adopt_engine(self, engine: DynamicBatcher) -> None:
+        self._engine = engine
+
+    def lane_stats(self) -> Dict[str, Dict]:
+        """The batching engine's per-lane counters while the server runs
+        (``DynamicBatcher.stats``: rows, frames, batches, ``busy_s``,
+        ``queue_wait_s``, live queue depth); ``{}`` when the plan does not
+        batch. ``batch_stats`` holds the same records only after
+        ``stop``."""
+        return self._engine.stats() if self._engine is not None else {}
 
     def stop(self, timeout: float = 10.0) -> None:
         """Signal the serve loop to quit and join its thread (seconds):

@@ -161,6 +161,35 @@ def test_ragged_final_batch_padding_masked_out(setup, bank):
     assert lane["padded_rows"] == 3
 
 
+def test_lone_frame_waits_out_the_window(setup, bank):
+    """A frame that no other frame joins waits the whole batch window
+    before its cloud call, and ``queue_wait_s`` counts that wait once."""
+    eng = DynamicBatcher(bank, BatchingPolicy(max_batch=8, max_wait_ms=30.0))
+    feat = np.asarray(bank.get(SPLIT)[0](setup[3][0]))
+    eng.submit(SPLIT, "fp32", feat).result(timeout=30)
+    eng.stop()
+    lane = next(iter(eng.stats().values()))
+    assert lane["frames"] == 1
+    assert lane["queue_wait_s"] >= 0.030
+
+
+def test_bank_names_each_program_by_half_and_split(setup, bank):
+    """A profiler trace names a device program after its jitted function:
+    each half carries its side and split, the row-mapped variant
+    ``_rows``, so a trace tells the edge from the cloud by name."""
+    x = setup[3][0]
+    edge_fn, cloud_fn, _ = bank.get(SPLIT)
+    feat = np.asarray(edge_fn(x))
+    _, cloud_rows, _ = bank.get(SPLIT, batch_bucket=2)
+
+    def module(fn, arg):
+        return fn.func.lower(*fn.args, arg).as_text().split()[1]
+    assert module(edge_fn, x) == f"@jit_edge_c{SPLIT}"
+    assert module(cloud_fn, feat) == f"@jit_cloud_c{SPLIT}"
+    assert (module(cloud_rows, np.repeat(feat, 2, axis=0))
+            == f"@jit_cloud_c{SPLIT}_rows")
+
+
 def test_submit_rejects_oversized_frame(setup, bank):
     eng = DynamicBatcher(bank, BatchingPolicy(max_batch=2))
     with pytest.raises(ValueError):
@@ -359,6 +388,31 @@ def test_batched_socket_serving_bit_identical_and_batching(setup):
     lane = next(iter(stats.values()))
     assert lane["rows"] == 2 * len(imgs)
     assert lane["avg_batch"] > 1.0               # cross-client fusion
+
+
+def test_cloud_server_lane_stats_read_live(setup):
+    """``CloudServer.lane_stats`` reads the batcher's counters while the
+    server runs, the same records ``batch_stats`` gets at shutdown, and
+    is empty for a plan that does not batch."""
+    cfg, params, masks, imgs = setup
+    plan = serving.DeploymentPlan.from_args(
+        params, cfg, SPLIT, masks=masks, compact=True, codec="int8",
+        shape_link=False, port=29863,
+        batching=BatchingPolicy(max_batch=4, max_wait_ms=2.0))
+    with serving.CloudServer(plan, max_clients=None) as srv:
+        with serving.connect(plan, backend="socket") as s:
+            s.infer_many(imgs[:5])
+        live = srv.lane_stats()
+        srv.stop()
+    lane = next(iter(live.values()))
+    assert lane["rows"] == lane["frames"] == 5
+    assert lane["queue_wait_s"] > 0
+    assert srv.batch_stats[next(iter(live))]["rows"] == 5
+    plain = serving.DeploymentPlan.from_args(
+        params, cfg, SPLIT, masks=masks, compact=True, codec="int8",
+        shape_link=False, port=29864)
+    with serving.CloudServer(plain, max_clients=None) as srv:
+        assert srv.lane_stats() == {}
 
 
 def test_infer_batch_handles_multi_row_requests(setup):
