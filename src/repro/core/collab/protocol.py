@@ -19,9 +19,15 @@ it, so raw-fp32 and codec peers interoperate without a handshake:
     ndim   u16  (of the LOGICAL full shape)
     shape  ndim * u64
     [packed]  keep bitmask over the last axis, ceil(shape[-1] / 8) bytes
-    [int8]    scale f32, zero f32                  (x ~= q * scale + zero)
+    [int8]    rows x (scale f32, zero f32)         (x ~= q * scale + zero)
     nbytes u64
     payload
+
+An int8 frame quantizes each leading row on its own (``rows`` is
+``shape[0]``, or 1 for a tensor of fewer than two axes): a one-row frame
+carries one pair, and each row of a k-row frame carries exactly the codes
+and pair its own one-row frame would, so a row's value never depends on the
+rows it travels with.
 
 ``decode_feature`` always reconstructs a float32 tensor at the logical full
 shape, with zeros in the pruned (non-kept) channel slots — exactly what
@@ -199,6 +205,37 @@ def affine_quantize(x: np.ndarray,
     return q, scale, zero
 
 
+def _by_rows(x: np.ndarray, rows: int) -> np.ndarray:
+    """``x`` as ``(rows, elements per row)``."""
+    return x.reshape(rows, x.size // rows if rows else 0)
+
+
+def _rows(shape: Tuple[int, ...]) -> int:
+    """Leading rows of a feature tensor, each quantized on its own: the
+    batch axis of a tensor of two or more axes, else one row."""
+    return int(shape[0]) if len(shape) >= 2 else 1
+
+
+def _quantize_rows(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """``affine_quantize(row, 255)`` of each leading row of float32 ``x``,
+    in one pass -> (uint8 codes shaped like ``x``, ``(rows, 2)`` little-
+    endian float32 ``(scale, zero)`` pairs). Each row's codes and pair are
+    bit for bit those of its own one-row call: the range is taken in
+    float64 and the codes computed in float32, as there."""
+    flat = _by_rows(x, _rows(x.shape))
+    if flat.shape[1]:
+        mn = flat.min(axis=1).astype(np.float64)
+        mx = flat.max(axis=1).astype(np.float64)
+    else:
+        mn = mx = np.zeros(flat.shape[0])
+    scale = (mx - mn) / 255.0
+    scale[scale == 0] = 1.0
+    q = np.clip(np.rint((flat - mn.astype(np.float32)[:, None])
+                        / scale.astype(np.float32)[:, None]), 0, 255)
+    qparams = np.stack([scale, mn], axis=1).astype("<f4")
+    return q.astype(np.uint8).reshape(x.shape), qparams
+
+
 def encode_feature(arr: np.ndarray, codec: str = "fp32",
                    keep: Optional[np.ndarray] = None) -> bytes:
     """Encode an intermediate-feature tensor for the wire.
@@ -206,8 +243,8 @@ def encode_feature(arr: np.ndarray, codec: str = "fp32",
     ``keep`` — optional surviving-unit indices along the LAST axis (from
     ``repro.models.cnn.split_keep_indices``): only those slices are
     shipped; the decoder zero-fills the rest. ``codec`` picks the payload
-    precision; int8 uses per-frame affine quantization (max-abs-error
-    <= scale/2 where scale = (max-min)/255).
+    precision; int8 uses affine quantization per leading row (max-abs-error
+    <= scale/2 where scale = (max-min)/255 of the row).
     """
     if codec not in CODEC_IDS:
         raise ValueError(f"unknown codec {codec!r} (use {list(CODEC_IDS)})")
@@ -221,8 +258,8 @@ def encode_feature(arr: np.ndarray, codec: str = "fp32",
     if codec == "fp16":
         payload_arr = x.astype(np.float16)
     elif codec == "int8":
-        payload_arr, scale, zero = affine_quantize(x, levels=255)
-        extra = struct.pack("<ff", scale, zero)
+        payload_arr, qparams = _quantize_rows(x)
+        extra = qparams.tobytes()
     else:
         payload_arr = x
     payload = payload_arr.tobytes()
@@ -259,10 +296,10 @@ def decode_feature(buf: bytes) -> Tuple[np.ndarray, int]:
                              count=full_shape[-1])
         keep = np.nonzero(bits)[0]
         off += n_mask_bytes
-    scale, zero = 1.0, 0.0
     if codec == "int8":
-        scale, zero = struct.unpack_from("<ff", buf, off)
-        off += 8
+        qparams = np.frombuffer(buf, "<f4", count=2 * _rows(full_shape),
+                                offset=off).reshape(-1, 2)
+        off += qparams.nbytes
     (nbytes,) = struct.unpack_from("<Q", buf, off)
     off += 8
     dtype = np.dtype(_CODEC_DTYPE[codec])
@@ -270,7 +307,8 @@ def decode_feature(buf: bytes) -> Tuple[np.ndarray, int]:
     raw = np.frombuffer(buf, dtype, count=nbytes // dtype.itemsize,
                         offset=off).reshape(wire_shape)
     if codec == "int8":
-        x = raw.astype(np.float32) * scale + zero
+        rows = _by_rows(raw, len(qparams)).astype(np.float32)
+        x = (rows * qparams[:, :1] + qparams[:, 1:]).reshape(wire_shape)
     elif raw.dtype == np.float32:
         x = raw          # zero-copy (read-only view) on the fp32 hot path
     else:

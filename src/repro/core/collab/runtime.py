@@ -61,9 +61,10 @@ payload, and is excluded.
 *Host spans* of the socket path (``jax.profiler.TraceAnnotation``, on the
 same clock as the device's operations while a profiler trace runs, one
 cheap check each otherwise): ``repro.edge.forward``, ``repro.edge.encode``
-and ``repro.edge.decode`` on the edge, ``repro.cloud.decode`` and
-``repro.cloud.encode`` in the server, and the batcher's
-``repro.batcher.fill`` and ``repro.cloud.call``. None wraps a blocking
+and ``repro.edge.decode`` on the edge (the first two with the ``rows``
+of the frame they make), ``repro.cloud.decode`` and ``repro.cloud.encode``
+in the server, and the batcher's ``repro.batcher.fill`` and
+``repro.cloud.call``. None wraps a blocking
 socket read or the wait for a queued item, except that ``fill`` holds the
 batch window by design.
 """
@@ -1014,6 +1015,9 @@ class EdgeClient:
         runs edge compute + transmission while a receiver thread drains
         responses, so edge compute of request i+1 overlaps the network and
         cloud time of request i. Results come back in submission order.
+        ``submit_group(images)`` sends several requests as ONE data frame
+        of their rows (one encode, one send, one logits response), each
+        request still forwarded by the batch-1 edge program.
     Do not interleave ``infer`` with outstanding ``submit``s.
 
     ``resplit(split)`` moves the partition point on the live connection
@@ -1181,22 +1185,28 @@ class EdgeClient:
         self.use_crc = bool(hello_caps(buf) & CAP_CRC)
 
     # -- framing ------------------------------------------------------------
-    def _forward(self, image: np.ndarray):
-        """The edge half of one request, ended by ``block_until_ready``:
-        the interval ``t_edge`` times."""
+    def _forward(self, images: Sequence[np.ndarray]) -> List:
+        """The edge half of a group of requests, each through the batch-1
+        program, dispatched back to back and ended by one
+        ``block_until_ready``: the interval ``t_edge`` times. Returns the
+        requests' device features, in order."""
+        rows = sum(int(np.shape(im)[0]) for im in images)
         with jax.profiler.TraceAnnotation("repro.edge.forward",
-                                          split=self.split):
-            x = jnp.asarray(image)
+                                          split=self.split, rows=rows):
+            xs = [jnp.asarray(im) for im in images]
             if self.edge_fn is not None:
-                x = self.edge_fn(x)
-                jax.block_until_ready(x)
-        return x
+                xs = [self.edge_fn(x) for x in xs]
+                jax.block_until_ready(xs)
+        return xs
 
-    def _encode_payload(self, x: np.ndarray) -> bytes:
-        """Frame payload (excluding the 8-byte length prefix): the prefix
-        is transport framing, so reported ``tx_bytes`` stays comparable
-        with the in-process executors' payload accounting."""
-        with jax.profiler.TraceAnnotation("repro.edge.encode"):
+    def _encode_payload(self, xs: Sequence) -> bytes:
+        """Frame payload of a group's features, rows in order (excluding
+        the 8-byte length prefix): the prefix is transport framing, so
+        reported ``tx_bytes`` stays comparable with the in-process
+        executors' payload accounting."""
+        x = np.concatenate([np.asarray(f) for f in xs])
+        with jax.profiler.TraceAnnotation("repro.edge.encode",
+                                          rows=int(x.shape[0])):
             if self.codec is None and self._keep is None:
                 return encode_tensor(x)
             return encode_feature(x, codec=self.codec or "fp32",
@@ -1343,9 +1353,9 @@ class EdgeClient:
         record ``{faults, retries, migrations, fallback}``."""
         rec = fault_record()
         t0 = time.perf_counter()
-        x = self._forward(image)
+        xs = self._forward([image])
         t1 = time.perf_counter()
-        payload = self._encode_payload(np.asarray(x))
+        payload = self._encode_payload(xs)
         self._seq = (self._seq + 1) & 0xFFFFFFFF
         seq = self._seq
         deadline = (time.monotonic() + self.policy.request_deadline_s
@@ -1454,6 +1464,9 @@ class EdgeClient:
                 "fault": dict(rec)}
 
     # -- pipelined (async) path ---------------------------------------------
+    # Work moves by group: the send queue holds (request ids, images), one
+    # data frame each; the receiver answers a group with one list of
+    # (id, result or error) on the out queue.
     def _sender_loop(self) -> None:
         while True:
             item = self._send_q.get()
@@ -1462,36 +1475,55 @@ class EdgeClient:
                 # every request enqueued before close() has been answered
                 self._inflight.put(None)
                 break
-            rid, image = item
+            rids, images = item
+            counts = [int(np.shape(im)[0]) for im in images]
             try:
                 t0 = time.perf_counter()
-                x = self._forward(image)
+                xs = self._forward(images)
                 t_edge = time.perf_counter() - t0
-                payload = self._encode_payload(np.asarray(x))
+                payload = self._encode_payload(xs)
                 self._send_payload(payload)
-                self._inflight.put((rid, t_edge, len(payload)))
+                self._inflight.put((rids, counts, t_edge, len(payload)))
             except Exception as e:                      # noqa: BLE001
-                self._inflight.put((rid, e, 0))
+                self._inflight.put((rids, counts, e, 0))
 
     def _receiver_loop(self) -> None:
         while True:
             item = self._inflight.get()
             if item is None:
                 break
-            rid, t_edge, nbytes = item
+            rids, counts, t_edge, nbytes = item
             if isinstance(t_edge, Exception):
-                self._out_q.put((rid, t_edge))
+                self._out_q.put([(rid, t_edge) for rid in rids])
                 continue
             try:
                 logits = self._recv_response()
-                self._out_q.put((rid, {"logits": logits, "t_edge": t_edge,
-                                       "tx_bytes": nbytes}))
             except Exception as e:                      # noqa: BLE001
-                self._out_q.put((rid, e))
+                self._out_q.put([(rid, e) for rid in rids])
+                continue
+            # each request gets its rows of the logits and its rows'
+            # share of the frame's bytes (the shares sum to the frame)
+            total, off, out = sum(counts), 0, []
+            for rid, n in zip(rids, counts):
+                out.append((rid, {
+                    "logits": logits[off:off + n], "t_edge": t_edge,
+                    "tx_bytes": (nbytes * (off + n) // total
+                                 - nbytes * off // total)}))
+                off += n
+            self._out_q.put(out)
 
     def submit(self, image: np.ndarray) -> int:
         """Enqueue a request; returns its id. Blocks only while the
         64-deep send queue is full (backpressure against a stalled link)."""
+        return self.submit_group([image])[0]
+
+    def submit_group(self, images: Sequence[np.ndarray]) -> List[int]:
+        """Enqueue requests that travel as ONE data frame of all their
+        rows, in order; returns their ids. Each result's ``t_edge`` is the
+        group's forward interval and its ``tx_bytes`` the frame's bytes
+        split by rows. The cloud answers the frame with one logits tensor,
+        so the group should fit the server's ``max_batch`` rows (a wider
+        frame bypasses its batcher)."""
         if self._send_q is None:
             self._send_q = queue.Queue(maxsize=64)
             self._inflight = queue.Queue()
@@ -1501,10 +1533,11 @@ class EdgeClient:
                                        self._receiver_loop)]
             for w in self._workers:
                 w.start()
-        rid = self._outstanding
-        self._outstanding += 1
-        self._send_q.put((rid, image))
-        return rid
+        rids = list(range(self._outstanding,
+                          self._outstanding + len(images)))
+        self._outstanding += len(images)
+        self._send_q.put((rids, list(images)))
+        return rids
 
     def collect(self, count: Optional[int] = None,
                 timeout: float = 60.0) -> List[Dict]:
@@ -1517,13 +1550,10 @@ class EdgeClient:
         out: List[Dict] = []
         while len(out) < count:
             rid = self._n_collected          # next id in submission order
-            if rid in self._ready:
-                res = self._ready.pop(rid)
-            else:
-                got_rid, res = self._out_q.get(timeout=timeout)
-                if got_rid != rid:
-                    self._ready[got_rid] = res
-                    continue
+            if rid not in self._ready:
+                self._ready.update(self._out_q.get(timeout=timeout))
+                continue
+            res = self._ready.pop(rid)
             self._n_collected += 1
             if isinstance(res, Exception):
                 raise res

@@ -72,7 +72,7 @@ edge-only fallback, exactly as for a single-server cloud death.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -99,6 +99,23 @@ def _controller_for(plan: DeploymentPlan) -> Optional[AdaptiveSplitController]:
         plan.cfg, plan.adaptive, plan.split, plan.profile, masks=plan.masks,
         compact=plan.compact, codec=plan.codec, pack=plan.pack,
         energy=plan.energy)
+
+
+def _row_groups(images: Sequence[np.ndarray], max_rows: int
+                ) -> List[Tuple[List[np.ndarray], int]]:
+    """Consecutive requests, in order, as (group, rows) with at most
+    ``max_rows`` rows a group (a multi-row request counts its rows); a
+    request wider than ``max_rows`` forms a group alone."""
+    groups: List[List[np.ndarray]] = []
+    rows: List[int] = []
+    for img in images:
+        n = int(np.shape(img)[0])
+        if not groups or rows[-1] + n > max_rows:
+            groups.append([])
+            rows.append(0)
+        groups[-1].append(img)
+        rows[-1] += n
+    return list(zip(groups, rows))
 
 
 def _result(logits, t_edge: Optional[float], t_upstream: Optional[float],
@@ -210,32 +227,16 @@ class LocalSession(InferenceSession):
         mb = self.plan.batching.max_batch
         buckets = self.plan.batching.resolved_buckets
         out: List[Dict] = []
-        chunk: List[np.ndarray] = []
-        chunk_rows = 0
-
-        def flush():
-            nonlocal chunk, chunk_rows
+        for group, rows in _row_groups(images, mb):
+            if rows > mb:                # one request wider than any bucket
+                out.append(self.infer(group[0]))
+                continue
             for r in self._runner.infer_batch(
-                    chunk, bucket=bucket_for(chunk_rows, buckets)):
+                    group, bucket=bucket_for(rows, buckets)):
                 t = r["timing"]
                 out.append(_result(r["logits"], t.t_device,
                                    t.t_tx + t.t_server, t.tx_bytes,
                                    t.e_edge_j, fault=r.get("fault")))
-            chunk, chunk_rows = [], 0
-
-        for img in images:
-            rows = int(np.asarray(img).shape[0])
-            if rows > mb:                # wider than any bucket
-                if chunk:
-                    flush()
-                out.append(self.infer(img))
-                continue
-            if chunk_rows + rows > mb:
-                flush()
-            chunk.append(img)
-            chunk_rows += rows
-        if chunk:
-            flush()
         return out
 
 
@@ -345,8 +346,19 @@ class SocketSession(InferenceSession):
                        fault=rec)
 
     def infer_many(self, images: Sequence[np.ndarray]) -> List[Dict]:
-        """Pipelined submit/collect: edge compute of request i+1 overlaps
-        network + cloud time of request i. Results in submission order.
+        """Pipelined submit/collect: edge compute of frame i+1 overlaps
+        network + cloud time of frame i. Results in submission order.
+
+        On a plan with a ``batching`` section the requests travel in
+        groups: consecutive requests, up to ``max_batch`` rows, share ONE
+        data frame (a request wider than ``max_batch`` goes alone), so the
+        socket, codec and thread hand-offs are paid once a group. Each
+        request still runs the batch-1 edge program, and the int8 codec
+        quantizes each row on its own, so every answer is bit-identical
+        to its own frame's. A grouped result's ``t_edge`` is its group's
+        forward interval and its ``tx_bytes`` the frame's bytes split by
+        rows. Without ``batching`` every request is its own frame: that
+        server would run a multi-row frame as one batch-k cloud call.
 
         With an adaptive plan this falls back to the sequential per-request
         loop: the control loop needs a per-request uplink observation and a
@@ -355,8 +367,13 @@ class SocketSession(InferenceSession):
         frames)."""
         if self._controller is not None:
             return [self.infer(img) for img in images]
-        for img in images:
-            self._client.submit(img)
+        if self.plan.batching is None:
+            groups = [[img] for img in images]
+        else:
+            groups = [g for g, _ in _row_groups(images,
+                                                self.plan.batching.max_batch)]
+        for group in groups:
+            self._client.submit_group(group)
         out = self._client.collect(len(images))
         return [_result(r["logits"], r["t_edge"], None, r["tx_bytes"],
                         fault=r.get("fault"))

@@ -393,7 +393,8 @@ def test_batched_socket_serving_bit_identical_and_batching(setup):
 def test_cloud_server_lane_stats_read_live(setup):
     """``CloudServer.lane_stats`` reads the batcher's counters while the
     server runs, the same records ``batch_stats`` gets at shutdown, and
-    is empty for a plan that does not batch."""
+    is empty for a plan that does not batch. ``infer_many`` sends its 5
+    single-row requests at ``max_batch`` 4 as two frames (4 + 1 rows)."""
     cfg, params, masks, imgs = setup
     plan = serving.DeploymentPlan.from_args(
         params, cfg, SPLIT, masks=masks, compact=True, codec="int8",
@@ -405,7 +406,7 @@ def test_cloud_server_lane_stats_read_live(setup):
         live = srv.lane_stats()
         srv.stop()
     lane = next(iter(live.values()))
-    assert lane["rows"] == lane["frames"] == 5
+    assert lane["rows"] == 5 and lane["frames"] == 2
     assert lane["queue_wait_s"] > 0
     assert srv.batch_stats[next(iter(live))]["rows"] == 5
     plain = serving.DeploymentPlan.from_args(
@@ -413,6 +414,104 @@ def test_cloud_server_lane_stats_read_live(setup):
         shape_link=False, port=29864)
     with serving.CloudServer(plain, max_clients=None) as srv:
         assert srv.lane_stats() == {}
+
+
+# ---------------------------------------------------------------------------
+# socket infer_many: one data frame per group of requests
+# ---------------------------------------------------------------------------
+def _int8_plan(setup, port, **kw):
+    cfg, params, masks, _ = setup
+    return serving.DeploymentPlan.from_args(
+        params, cfg, SPLIT, masks=masks, compact=True, codec="int8",
+        shape_link=False, port=port, **kw)
+
+
+def _lane_delta(srv, before):
+    after = next(iter(srv.lane_stats().values()))
+    return {k: after[k] - before.get(k, 0) for k in ("rows", "frames")}
+
+
+def test_socket_infer_many_groups_bit_identical_to_infer(setup):
+    """On an int8, compacted, batching plan ``infer_many`` of n single-row
+    requests sends ceil(n / max_batch) frames, answers bitwise as n
+    ``infer`` calls do, splits each frame's bytes over its requests, and
+    compiles nothing new on either peer."""
+    cfg, params, masks, imgs = setup
+    plan = _int8_plan(setup, 29866,
+                      batching=BatchingPolicy(max_batch=4, max_wait_ms=2.0))
+    with serving.CloudServer(plan, max_clients=None) as srv:
+        with serving.connect(plan, backend="socket") as s:
+            singles = [s.infer(im) for im in imgs]
+            before = next(iter(srv.lane_stats().values()))
+            traces = (s._client._bank.n_traces, srv._engine.bank.n_traces)
+            grouped = s.infer_many(imgs)
+            assert (s._client._bank.n_traces,
+                    srv._engine.bank.n_traces) == traces
+            feat = np.asarray(s._client.edge_fn(imgs[0]))
+        lane = _lane_delta(srv, before)
+        srv.stop()
+    assert lane == {"rows": len(imgs), "frames": -(-len(imgs) // 4)}
+    for a, b in zip(singles, grouped):
+        assert np.array_equal(a["logits"], b["logits"])
+    for start in range(0, len(imgs), 4):
+        group = grouped[start:start + 4]
+        frame = encode_feature(np.zeros((len(group),) + feat.shape[1:],
+                                        np.float32), codec="int8")
+        sizes = [r["tx_bytes"] for r in group]
+        assert sum(sizes) == len(frame) and max(sizes) - min(sizes) <= 1
+        assert len({r["t_edge"] for r in group}) == 1
+
+
+def test_socket_infer_many_groups_by_rows(setup):
+    """A 2-row request and singles share a frame up to ``max_batch`` rows;
+    a request wider than ``max_batch`` goes alone (past the batcher); the
+    answers equal the same requests sent one ``infer`` at a time."""
+    imgs = setup[3]
+    two = np.concatenate(imgs[:2], axis=0)
+    wide = np.concatenate(imgs[2:7], axis=0)         # 5 rows > max_batch 4
+    batch = [two, imgs[7], imgs[8], wide, imgs[9], two]
+    plan = _int8_plan(setup, 29867,
+                      batching=BatchingPolicy(max_batch=4, max_wait_ms=2.0))
+    with serving.CloudServer(plan, max_clients=None) as srv:
+        with serving.connect(plan, backend="socket") as s:
+            want = [s.infer(x)["logits"] for x in batch]
+            before = next(iter(srv.lane_stats().values()))
+            got = s.infer_many(batch)
+        lane = _lane_delta(srv, before)
+        srv.stop()
+    # frames [two, 7, 8], [wide] (not batched), [9, two]
+    assert lane == {"rows": 7, "frames": 2}
+    assert [r["logits"].shape[0] for r in got] == [2, 1, 1, 5, 1, 2]
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b["logits"])
+
+
+@pytest.mark.parametrize("case", ["no_batching", "adaptive", "infer"])
+def test_socket_requests_stay_one_frame_each_without_grouping(setup, case):
+    """A plan without ``batching``, an adaptive plan's sequential fallback,
+    and the synchronous ``infer`` all send one frame per request."""
+    from repro.core.collab.adaptive import AdaptivePolicy
+    imgs = setup[3][:5]
+    kw = {"no_batching": {},
+          "adaptive": {"adaptive": AdaptivePolicy(candidates=(SPLIT,)),
+                       "batching": BatchingPolicy(max_batch=4)},
+          "infer": {"batching": BatchingPolicy(max_batch=4)}}[case]
+    plan = _int8_plan(setup, 29868 + ["no_batching", "adaptive",
+                                      "infer"].index(case), **kw)
+    with serving.CloudServer(plan, max_clients=None) as srv:
+        with serving.connect(plan, backend="socket") as s:
+            encode = s._client._encode_payload
+            rows = []
+
+            def counted(xs):
+                rows.append(sum(int(x.shape[0]) for x in xs))
+                return encode(xs)
+            s._client._encode_payload = counted
+            out = ([s.infer(im) for im in imgs] if case == "infer"
+                   else s.infer_many(imgs))
+        srv.stop()
+    assert len(out) == len(imgs)
+    assert rows == [1] * len(imgs)
 
 
 def test_infer_batch_handles_multi_row_requests(setup):

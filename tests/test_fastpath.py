@@ -12,9 +12,10 @@ import numpy as np
 import pytest
 
 from repro.core.collab.channel import recv_exact
-from repro.core.collab.protocol import (CODEC_TX_SCALE, decode_any,
+from repro.core.collab.protocol import (CODEC_TX_SCALE, FEATURE_MAGIC,
+                                        affine_quantize, decode_any,
                                         decode_feature, encode_feature,
-                                        encode_tensor)
+                                        encode_tensor, frame_lane)
 from repro.core.collab.runtime import (CollabRunner, EdgeClient,
                                        deploy_submodels, serve_cloud)
 from repro.core.collab.streaming import StreamingCollabRunner
@@ -144,6 +145,77 @@ def test_codec_packed_roundtrip_restores_zeros():
     raw = len(encode_tensor(x))
     packed = len(encode_feature(x, codec="int8", keep=keep))
     assert packed < 0.25 * raw
+
+
+def _single_pair_int8_frame(x, keep=None):
+    """An int8 feature frame as one (scale, zero) pair for the whole
+    tensor encodes it: the layout of a one-row frame."""
+    shape = x.shape
+    if keep is not None:
+        x = np.ascontiguousarray(x[..., keep])
+    q, scale, zero = affine_quantize(x, levels=255)
+    mask = b""
+    if keep is not None:
+        bits = np.zeros(shape[-1], np.uint8)
+        bits[keep] = 1
+        mask = np.packbits(bits).tobytes()
+    return (struct.pack("<IBBH", FEATURE_MAGIC, 2, int(keep is not None),
+                        len(shape))
+            + struct.pack(f"<{len(shape)}Q", *shape) + mask
+            + struct.pack("<ff", scale, zero)
+            + struct.pack("<Q", q.nbytes) + q.tobytes())
+
+
+@pytest.mark.parametrize("shape,packed", [((1, 6, 6, 24), False),
+                                          ((1, 6, 6, 24), True),
+                                          ((1, 37), False), ((37,), False)])
+def test_int8_one_row_frame_is_the_single_pair_encoding(shape, packed):
+    rng = np.random.RandomState(3)
+    keep = np.array([0, 3, 4, 20]) if packed else None
+    for seed in range(20):
+        x = (rng.randn(*shape) * 10.0 ** rng.randint(-3, 4)
+             + rng.randn()).astype(np.float32)
+        assert encode_feature(x, codec="int8", keep=keep) == \
+            _single_pair_int8_frame(x, keep), seed
+    flat = np.full(shape, 2.5, np.float32)           # degenerate range
+    assert encode_feature(flat, codec="int8") == _single_pair_int8_frame(flat)
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_int8_rows_of_a_multi_row_frame_decode_as_their_own_frames(packed):
+    """Each row of a k-row int8 frame carries exactly the codes and
+    (scale, zero) of its own one-row frame, so it decodes bitwise alike
+    however it is grouped; both frames keep the ``int8`` lane."""
+    rng = np.random.RandomState(4)
+    keep = np.array([1, 5, 6, 10, 23]) if packed else None
+    # rows of very different ranges, one of them constant
+    x = np.stack([rng.randn(6, 6, 24) * s for s in (1e-3, 1.0, 50.0, 0.0)]
+                 ).astype(np.float32) + np.float32(0.25)
+    frame = encode_feature(x, codec="int8", keep=keep)
+    ones = [encode_feature(x[i:i + 1], codec="int8", keep=keep)
+            for i in range(len(x))]
+    got, used = decode_feature(frame)
+    assert used == len(frame)
+    for i, one in enumerate(ones):
+        want, _ = decode_feature(one)
+        assert got[i:i + 1].tobytes() == want.tobytes(), i
+    # the header's pairs and the payload's codes are the rows' own
+    at = _pair_offset(ones[0])
+    pairs = [o[at:at + 8] for o in ones]
+    codes = [o[at + 16:] for o in ones]         # after the pair and nbytes
+    assert frame[at:at + 8 * len(x)] == b"".join(pairs)
+    assert frame[at + 8 * len(x) + 8:] == b"".join(codes)
+    assert frame_lane(frame) == frame_lane(ones[0]) == (
+        "int8+packed" if packed else "int8")
+
+
+def _pair_offset(one_row_frame):
+    """Where the (scale, zero) pair starts in a one-row int8 frame: after
+    the fixed header, the shape and any keep bitmask."""
+    ndim = struct.unpack_from("<H", one_row_frame, 6)[0]
+    packed = one_row_frame[5]
+    shape = struct.unpack_from(f"<{ndim}Q", one_row_frame, 8)
+    return 8 + 8 * ndim + ((shape[-1] + 7) // 8 if packed else 0)
 
 
 def test_decode_any_dispatches_both_frames():

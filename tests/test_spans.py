@@ -1,7 +1,8 @@
 """The served socket path's host spans, recorded by a real profiler trace
 on the CPU: two pipelined edges against one batching cloud, every
-``repro.*`` stage of every request present, and the batch spans agreeing
-with the batcher's own counters."""
+``repro.*`` stage of every request present (once per data frame, which
+carries a group of requests), and the batch spans agreeing with the
+batcher's own counters."""
 from __future__ import annotations
 
 import glob
@@ -17,10 +18,12 @@ from repro.core.collab.batching import BatchingPolicy
 from repro.models.cnn import init_cnn_params, tiny_cnn_config
 
 SPLIT = 3
-#: one span per request of these stages
-PER_REQUEST = ("repro.edge.forward", "repro.edge.encode",
-               "repro.cloud.decode", "repro.cloud.encode",
-               "repro.edge.decode")
+#: one span per data frame of these stages
+PER_FRAME = ("repro.edge.forward", "repro.edge.encode",
+             "repro.cloud.decode", "repro.cloud.encode",
+             "repro.edge.decode")
+#: the frame's stages on the edge that name its rows
+WITH_ROWS = ("repro.edge.forward", "repro.edge.encode")
 #: one span per cloud call
 PER_BATCH = ("repro.batcher.fill", "repro.cloud.call")
 
@@ -70,11 +73,19 @@ def traced_window(tmp_path_factory):
 
 
 def test_every_request_stage_has_one_span_per_request(traced_window):
-    spans, _, requests = traced_window
+    """Each edge's 6 requests travel as two frames (4 + 2 rows at
+    ``max_batch`` 4): every stage has one span a frame, and the rows the
+    edge spans name cover each request once."""
+    spans, lane, requests = traced_window
     n = Counter(name for name, _ in spans)
-    assert set(n) == set(PER_REQUEST) | set(PER_BATCH)
-    for name in PER_REQUEST:
-        assert n[name] == requests, name
+    assert set(n) == set(PER_FRAME) | set(PER_BATCH)
+    assert lane["frames"] == 4
+    for name in PER_FRAME:
+        assert n[name] == lane["frames"], name
+    for name in WITH_ROWS:
+        assert sorted(a["rows"] for nm, a in spans if nm == name) == [
+            2, 2, 4, 4], name
+        assert sum(a["rows"] for nm, a in spans if nm == name) == requests
 
 
 def test_batch_spans_match_the_batcher_counters(traced_window):
