@@ -6,10 +6,12 @@ compaction, so every latency/energy number downstream of ``sweep_splits``
 was modeled, never measured. This module wires the edge forward through
 the ``kernels/masked_matmul`` column-masked GEMM instead:
 
-  * **conv layers** lower to im2col
-    (``jax.lax.conv_general_dilated_patches``, channel-major patch
-    features) followed by one masked GEMM against the HWIO weights
-    re-laid-out as ``(Cin*kh*kw, Cout)``;
+  * **conv layers** lower to im2col (``im2col``: the spatially padded
+    activation's strided slices, concatenated tap-major into the patch
+    matrix, large kernels in two stages, K zero-padded to the compiled
+    kernel's block)
+    followed by one masked GEMM against the HWIO weights re-laid-out as
+    ``(kh*kw*Cin, Cout)``;
   * **dense layers** are the masked GEMM directly;
   * relu / maxpool / flatten keep the exact ``models.cnn.cnn_apply``
     ops, so those layers stay bit-identical to the dense reference.
@@ -73,6 +75,14 @@ CALIBRATIONS: Tuple[str, ...] = ("minmax",)
 #: bit-identical to the XLA ref GEMM (the basis of the differential
 #: suite's exactness contract). Compiled TPU runs keep the native 128s.
 WHOLE_BLOCK = 1 << 30
+
+#: the K block ``_gemm`` launches the compiled kernel with: each conv's
+#: patch matrix and weight rows are padded to it (``gemm_block_k``)
+KERNEL_BLOCK_K = 128
+
+#: ``im2col`` builds a conv of this kernel size or larger in two stages
+#: (``im2col_builder``)
+TWO_STAGE_MIN_KERNEL = 5
 
 
 @dataclass(frozen=True)
@@ -169,11 +179,83 @@ def quantize_weights(w: np.ndarray, bits: int, per_channel: bool = True
 
 def conv_weight_gemm_layout(w: np.ndarray) -> np.ndarray:
     """HWIO conv weights ``(kh, kw, Cin, N)`` -> the im2col GEMM operand
-    ``(Cin*kh*kw, N)``. The row order is channel-major ``(c, kh, kw)``
-    to match ``conv_general_dilated_patches``'s NHWC feature layout."""
+    ``(kh*kw*Cin, N)``. The row order is tap-major ``(kh, kw, c)``, the
+    column order of ``im2col``'s patch matrix: HWIO's own, so the
+    re-layout is a reshape."""
     kh, kw, cin, n = w.shape
-    return np.transpose(np.asarray(w, np.float32),
-                        (2, 0, 1, 3)).reshape(cin * kh * kw, n)
+    return np.asarray(w, np.float32).reshape(kh * kw * cin, n)
+
+
+def gemm_block_k(backend: str, interpret: bool) -> int:
+    """The K block ``_gemm`` launches the kernel with: ``KERNEL_BLOCK_K``
+    compiled, one whole block for the ``ref`` backend and interpret mode."""
+    return WHOLE_BLOCK if backend == "ref" or interpret else KERNEL_BLOCK_K
+
+
+def padded_k(k: int, block_k: int) -> int:
+    """``k`` rounded up to the kernel's K block, ``min(block_k, k)`` (the
+    block ``ops.masked_matmul`` clamps to): unchanged while ``k`` fits in
+    one block."""
+    bk = min(block_k, k)
+    return -(-k // bk) * bk
+
+
+def im2col_builder(kernel: int) -> str:
+    """Which of ``im2col``'s two builders a conv of this kernel size takes,
+    by the ``jax.named_scope`` its patch ops carry in the program and its
+    device trace: ``"im2col_slices"`` (one slice per tap) below
+    ``TWO_STAGE_MIN_KERNEL``, ``"im2col_two_stage"`` from it up."""
+    return ("im2col_two_stage" if kernel >= TWO_STAGE_MIN_KERNEL
+            else "im2col_slices")
+
+
+def im2col(x: jnp.ndarray, kernel: int, stride: int, padding: int,
+           block_k: int = WHOLE_BLOCK) -> jnp.ndarray:
+    """NHWC ``x`` -> the conv's im2col GEMM operand ``(N, Ho, Wo, K_pad)``,
+    whose leading dims the GEMM flattens into its M rows for free.
+
+    The activation is zero-padded spatially once, and its ``kernel**2``
+    strided slices are concatenated tap-major along the channel axis:
+    column ``(i*kw + j)*Cin + c`` holds channel ``c`` at tap ``(i, j)``,
+    the row order of ``conv_weight_gemm_layout``. A large kernel
+    (``im2col_builder``) takes two stages with the same result: its
+    ``kw`` W-slices side by side in the lanes, then ``kh`` H-slices of
+    that, so each slice moves ``kw`` times the channels. The values are
+    ``conv_general_dilated_patches``' bit for bit, whose grouped identity
+    convolution the TPU runs slowly and then relays out. ``K =
+    kh*kw*Cin`` is padded with exact zero columns to ``padded_k(K,
+    block_k)``, so the matrix is written once, already in the layout the
+    compiled kernel reads; the caller pads the weight rows to match."""
+    n, h, w, c = x.shape
+    ho = (h + 2 * padding - kernel) // stride + 1
+    wo = (w + 2 * padding - kernel) // stride + 1
+    k = c * kernel * kernel
+    k_pad = padded_k(k, block_k)
+    builder = im2col_builder(kernel)
+    with jax.named_scope(builder):
+        xp = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding),
+                         (0, 0)))
+        if builder == "im2col_two_stage":
+            # the kernel's W taps side by side in the lanes, then its H
+            # taps of that: 2*kernel slices in place of kernel**2
+            xp = jnp.concatenate(
+                [jax.lax.slice(xp, (0, 0, j, 0),
+                               (n, xp.shape[1], j + stride * (wo - 1) + 1,
+                                c), (1, 1, stride, 1))
+                 for j in range(kernel)], axis=-1)
+            taps = [jax.lax.slice(xp, (0, i, 0, 0),
+                                  (n, i + stride * (ho - 1) + 1, wo,
+                                   kernel * c), (1, stride, 1, 1))
+                    for i in range(kernel)]
+        else:
+            taps = [jax.lax.slice(xp, (0, i, j, 0),
+                                  (n, i + stride * (ho - 1) + 1,
+                                   j + stride * (wo - 1) + 1, c),
+                                  (1, stride, stride, 1))
+                    for i in range(kernel) for j in range(kernel)]
+        if k_pad > k:
+            taps.append(jnp.zeros((n, ho, wo, k_pad - k), x.dtype))
+        return jnp.concatenate(taps, axis=-1)
 
 
 def quantize_params(params, cfg: CNNConfig,
@@ -232,9 +314,11 @@ def _gemm(x: jnp.ndarray, w2: jnp.ndarray, mvec: jnp.ndarray,
         return masked_matmul_ref(x, w2, mvec)
     if interpret:
         return masked_matmul(x, w2, mvec, block_m=WHOLE_BLOCK,
-                             block_n=WHOLE_BLOCK, block_k=WHOLE_BLOCK,
+                             block_n=WHOLE_BLOCK,
+                             block_k=gemm_block_k(backend, interpret),
                              interpret=True)
-    return masked_matmul(x, w2, mvec)
+    return masked_matmul(x, w2, mvec,
+                         block_k=gemm_block_k(backend, interpret))
 
 
 def quant_cnn_apply(qparams, cfg: CNNConfig, x: jnp.ndarray,
@@ -255,12 +339,12 @@ def quant_cnn_apply(qparams, cfg: CNNConfig, x: jnp.ndarray,
         spec = cfg.layers[i]
         if spec.kind == "conv":
             lp = qparams[f"l{i}"]
-            w2 = dequantize_weights(lp)          # (Cin*kh*kw, N)
-            patches = jax.lax.conv_general_dilated_patches(
-                x, (spec.kernel, spec.kernel),
-                (spec.stride, spec.stride),
-                [(spec.padding, spec.padding)] * 2,
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            w2 = dequantize_weights(lp)          # (kh*kw*Cin, N)
+            patches = im2col(x, spec.kernel, spec.stride, spec.padding,
+                             gemm_block_k(backend, interpret))
+            # both operands reach the kernel at the same K_pad: the
+            # weights' pad rows meet the patches' zero columns
+            w2 = jnp.pad(w2, ((0, patches.shape[-1] - w2.shape[0]), (0, 0)))
             mvec = (masks[i].astype(jnp.float32) if i in masks
                     else jnp.ones((w2.shape[1],), jnp.float32))
             x = _gemm(patches, w2, mvec, backend, interpret) + lp["b"] * mvec

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 
 import jax
@@ -36,12 +37,14 @@ import numpy as np
 import pytest
 
 from repro import serving
+from repro.configs.base import CNNConfig, ConvLayerSpec
+from repro.core.collab import quant
 from repro.core.collab.protocol import affine_quantize
-from repro.core.collab.quant import (BITS_LEVELS, QuantPolicy,
-                                     calibrate_quant_edge,
+from repro.core.collab.quant import (BITS_LEVELS, KERNEL_BLOCK_K,
+                                     QuantPolicy, calibrate_quant_edge,
                                      conv_weight_gemm_layout,
                                      dequantize_weights, gemm_error_bound,
-                                     quant_cnn_apply, quantize_params,
+                                     im2col, quant_cnn_apply, quantize_params,
                                      quantize_weights, resolve_backend)
 from repro.core.partition.latency_model import (KernelCalibration,
                                                 cnn_input_bytes,
@@ -156,22 +159,134 @@ def test_per_channel_beats_per_tensor_on_ragged_ranges():
         assert small < np.abs(pt[:, :8] - w[:, :8]).max()
 
 
-def test_conv_weight_gemm_layout_matches_patch_order():
-    """The GEMM-layout conv weights reproduce the conv exactly through
-    im2col: (patches @ w2) == conv_general_dilated to float tolerance."""
-    kh = kw = 3
-    w = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (kh, kw, 4, 9)),
-                   np.float32)
-    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 8, 4))
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (kh, kw), (1, 1), [(1, 1)] * 2,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    got = patches @ jnp.asarray(conv_weight_gemm_layout(w))
+#: (kernel, stride, padding, Cin) of every distinct conv geometry of the
+#: benchmark's alexnet224 and vgg16pv configurations
+CONV_GEOMETRIES = [(11, 4, 2, 3), (5, 1, 2, 64), (3, 1, 1, 3), (3, 1, 1, 64),
+                   (3, 1, 1, 192)]
+
+
+@pytest.mark.parametrize("kernel,stride,padding,cin", CONV_GEOMETRIES)
+def test_conv_weight_gemm_layout_matches_patch_order(kernel, stride, padding,
+                                                     cin):
+    """``im2col``'s patch matrix, K-padded to the compiled kernel's block,
+    against the GEMM-layout conv weights reproduces the conv to float
+    tolerance; the pad columns are exact zeros, and the patch columns are
+    ``conv_general_dilated_patches``' values bit for bit, in the row order
+    ``conv_weight_gemm_layout`` gives the weights."""
+    k = cin * kernel * kernel
+    hw = 3 * stride + kernel
+    w = np.asarray(jax.random.normal(jax.random.PRNGKey(5),
+                                     (kernel, kernel, cin, 9)),
+                   np.float32) / np.sqrt(k)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, hw, hw, cin))
+    cols = np.asarray(im2col(x, kernel, stride, padding, KERNEL_BLOCK_K))
+    cols = cols.reshape(-1, cols.shape[-1])
+    assert cols.shape[1] == k or cols.shape[1] % KERNEL_BLOCK_K == 0
+    assert not np.any(cols[:, k:])
+    got = cols[:, :k] @ conv_weight_gemm_layout(w)
     want = jax.lax.conv_general_dilated(
-        x, w, (1, 1), [(1, 1)] * 2,
+        x, w, (stride, stride), [(padding, padding)] * 2,
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    np.testing.assert_allclose(got, np.asarray(want).reshape(-1, 9),
                                rtol=1e-4, atol=1e-5)
+    # column r of the builder is the library's channel-major column order[r]
+    channel_major = np.arange(k).reshape(cin, kernel, kernel)
+    order = conv_weight_gemm_layout(
+        channel_major.transpose(1, 2, 0)[..., None])[:, 0].astype(int)
+    patches = jax.lax.conv_general_dilated_patches(
+        x, (kernel, kernel), (stride, stride), [(padding, padding)] * 2,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    assert np.array_equal(cols[:, :k],
+                          np.asarray(patches).reshape(-1, k)[:, order])
+
+
+@pytest.mark.parametrize("kernel,stride,padding,cin", CONV_GEOMETRIES)
+def test_two_stage_patches_equal_one_stage_slices(kernel, stride, padding,
+                                                  cin, monkeypatch):
+    """Either builder ``im2col`` may take writes the same patch matrix,
+    bit for bit, on a non-square input."""
+    x = jax.random.normal(jax.random.PRNGKey(7),
+                          (2, 3 * stride + kernel + 1, 3 * stride + kernel,
+                           cin))
+    built = []
+    for rule in (1, 1 << 30):          # every kernel two-stage / none
+        monkeypatch.setattr(quant, "TWO_STAGE_MIN_KERNEL", rule)
+        built.append(np.asarray(im2col(x, kernel, stride, padding,
+                                       KERNEL_BLOCK_K)))
+    assert np.array_equal(*built)
+
+
+@pytest.mark.parametrize("kernel,stride,padding,cin", CONV_GEOMETRIES)
+def test_conv_in_compiled_kernel_layout_matches_ref(kernel, stride, padding,
+                                                    cin, monkeypatch):
+    """The layout the chip runs: ``quant_cnn_apply`` at the compiled
+    kernel's K block pads the patches' K with zero columns and the weight
+    rows to match, and the kernel (interpret mode, ``KERNEL_BLOCK_K``-wide
+    K blocks) then agrees with the ``ref`` GEMM on the unpadded patches."""
+    cfg = CNNConfig(name="one_conv", num_classes=2,
+                    input_hw=(3 * stride + kernel,) * 2, input_channels=cin,
+                    layers=(ConvLayerSpec("conv", out_channels=9,
+                                          kernel=kernel, stride=stride,
+                                          padding=padding),))
+    params = init_cnn_params(jax.random.PRNGKey(8), cfg)
+    qp = quantize_params(params, cfg, QuantPolicy(weight_bits=8))
+    x = jax.random.normal(jax.random.PRNGKey(9),
+                          (1, *cfg.input_hw, cin))
+    want = quant_cnn_apply(qp, cfg, x, backend="ref")
+    monkeypatch.setattr(quant, "gemm_block_k", lambda *_: KERNEL_BLOCK_K)
+    patches = im2col(x, kernel, stride, padding,
+                     quant.gemm_block_k("pallas", True))
+    assert (patches.shape[-1] == cin * kernel ** 2
+            or patches.shape[-1] % KERNEL_BLOCK_K == 0)
+    got = quant_cnn_apply(qp, cfg, x, backend="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _conv(n, kernel, stride, padding):
+    return ConvLayerSpec("conv", out_channels=n, kernel=kernel,
+                         stride=stride, padding=padding)
+
+
+#: the benchmark's AlexNet-224 conv stack (11x11/s4, 5x5, three 3x3) at
+#: small widths on a 67-px image; the edge ends after pool5 (layer 13)
+ALEXNET_PATTERN = CNNConfig(
+    name="alexnet_pattern", num_classes=4, input_hw=(67, 67),
+    input_channels=3,
+    layers=(_conv(8, 11, 4, 2), ConvLayerSpec("relu"),
+            ConvLayerSpec("maxpool", kernel=3, stride=2),
+            _conv(16, 5, 1, 2), ConvLayerSpec("relu"),
+            ConvLayerSpec("maxpool", kernel=3, stride=2),
+            _conv(16, 3, 1, 1), ConvLayerSpec("relu"),
+            _conv(16, 3, 1, 1), ConvLayerSpec("relu"),
+            _conv(16, 3, 1, 1), ConvLayerSpec("relu"),
+            ConvLayerSpec("maxpool", kernel=3, stride=2),
+            ConvLayerSpec("flatten"), ConvLayerSpec("dense", features=4)))
+
+
+def test_alexnet_pattern_edge_takes_each_builder_by_kernel_size():
+    """Lowered for the TPU (exported; no chip needed), the AlexNet-pattern
+    edge calls ``masked_matmul`` once per conv and holds no convolution;
+    its 11x11 and 5x5 convs take the two-stage builder, its three 3x3
+    convs the one-stage one, and the program's scopes say so."""
+    from collections import Counter
+    from jax import export
+    from repro.core.collab.runtime import split_step
+    cfg, split = ALEXNET_PATTERN, 13
+    qp = quantize_params(init_cnn_params(jax.random.PRNGKey(3), cfg), cfg,
+                         QuantPolicy(weight_bits=8))
+    qp = {k: v for k, v in qp.items() if int(k[1:]) < split}
+    step = split_step(cfg, split, "edge", None, ("pallas", False))
+    text = export.export(jax.jit(step), platforms=("tpu",))(
+        jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), qp),
+        jax.ShapeDtypeStruct((1, 67, 67, 3), jnp.float32)).mlir_module()
+    convs = [s for s in cfg.layers[:split] if s.kind == "conv"]
+    assert Counter(quant.im2col_builder(s.kernel) for s in convs) == {
+        "im2col_two_stage": 2, "im2col_slices": 3}
+    assert "/im2col_two_stage/" in text and "/im2col_slices/" in text
+    assert len(re.findall(r"call @masked_matmul", text)) == len(convs)
+    assert "stablehlo.convolution" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -3,15 +3,23 @@ stages of stacked 3x3/s1/p1 convs and 2x2 pools, 13 int8 convs on the
 edge, the split after pool5 (c = 31), a 38-way head. ``infer_many``'s
 grouped frames answer bit-identically to one ``infer`` per request, and
 the bank names its programs as the benchmark's device-time readers expect
-(``jit_edge_c31``, ``jit_cloud_c31_rows``)."""
+(``jit_edge_c31``, ``jit_cloud_c31_rows``). The edge program, lowered for
+the TPU, builds its im2col patches with no convolution."""
 from __future__ import annotations
 
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import export
 
 from repro import serving
 from repro.configs.base import CNNConfig, ConvLayerSpec
+from repro.core.collab.quant import (KERNEL_BLOCK_K, im2col_builder,
+                                     quantize_params)
+from repro.core.collab.runtime import split_step
 from repro.core.pruning.masks import cnn_masks_from_ratios
 from repro.models.cnn import init_cnn_params
 
@@ -106,3 +114,32 @@ def test_bank_names_its_programs_by_side_and_split(setup):
     assert "module @jit_edge_c31 " in edge_text
     assert "module @jit_cloud_c31_rows " in cloud_text
     assert feat.shape == (1, 1, 1, 32)
+
+
+def test_edge_program_for_tpu_builds_patches_without_convolution(setup):
+    """Lowered for the TPU (exported; no chip or TPU compiler needed), the
+    compiled-kernel edge step calls ``masked_matmul`` once per conv, each
+    call's patch operand already K-padded to the kernel's block, and holds
+    no convolution: the patches are slices, not a grouped identity conv,
+    and every 3x3 conv takes the one-stage builder."""
+    cfg, params, _, _ = setup
+    qp = quantize_params(params, cfg, serving.QuantPolicy(weight_bits=8))
+    qp = {k: v for k, v in qp.items() if int(k[1:]) < SPLIT}
+    step = split_step(cfg, SPLIT, "edge", None, ("pallas", False))
+    exp = export.export(jax.jit(step), platforms=("tpu",))(
+        jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), qp),
+        jax.ShapeDtypeStruct((1, 32, 32, 3), jnp.float32))
+    text = exp.mlir_module()
+    n_conv = sum(s.kind == "conv" for s in cfg.layers[:SPLIT])
+    patch_k = [int(k) for k in re.findall(
+        r"call @masked_matmul\w* ?\(.*?\) : \(tensor<(?:\d+x)+?(\d+)xf32>",
+        text)]
+    assert len(patch_k) == n_conv == 13
+    assert "tpu_custom_call" in text
+    assert "stablehlo.convolution" not in text
+    assert all(k <= KERNEL_BLOCK_K or k % KERNEL_BLOCK_K == 0
+               for k in patch_k), patch_k
+    convs = [s for s in cfg.layers[:SPLIT] if s.kind == "conv"]
+    assert {im2col_builder(s.kernel) for s in convs} == {"im2col_slices"}
+    assert "/im2col_slices/" in text and "im2col_two_stage" not in text
