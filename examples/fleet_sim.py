@@ -1,9 +1,11 @@
 """Fleet-scale simulation sweep: fleet size x cloudlet count x SLO mix.
 
+    PYTHONPATH=src python examples/fleet_sim.py [--smoke] [--json]
+
 The paper validates one edge against one cloud; the real question for
 the ROADMAP's "millions of users" north star is what happens when
 thousands of heterogeneous, battery-constrained, wireless edges share
-a cloudlet tier. This benchmark drives ``repro.core.fleet`` — the
+a cloudlet tier. This example drives ``repro.core.fleet`` — the
 virtual-clock discrete-event simulator — over a grid of scenarios and
 reports the numbers a fleet operator stares at: p50/p99 end-to-end
 latency, joules per request, % deadlines met, and % shed per reason,
@@ -22,17 +24,18 @@ Three properties are asserted, not just reported:
   3. **Conservation** — every arrival is accounted: served (collab or
      degraded-to-edge) + shed == arrivals, per cell.
 
-``--smoke`` runs the CI-sized grid; the tracked perf record
-``experiments/bench/BENCH_fleet.json`` is written by ``--json`` (or by
-``benchmarks.run --json``), next to the other BENCH records.
+``--smoke`` runs the CI-sized grid; ``--json`` prints the headline
+cell's rollup plus each cell's summary keys as one JSON object on the
+last line of stdout. All of it is virtual-clock, so bit-identical
+across same-seed runs.
 """
 from __future__ import annotations
 
 import argparse
+import json
 import time
 from typing import Dict, List
 
-from benchmarks.common import table, write_fleet_record
 from repro.core.collab.faults import FaultPolicy
 from repro.core.fleet import (DEFAULT_SLO_CLASSES, FleetScenario, SLOClass,
                               simulate_fleet)
@@ -53,7 +56,7 @@ SLO_MIXES = {"default": DEFAULT_SLO_CLASSES, "strict": STRICT_SLO_CLASSES}
 
 def _cells(fast: bool) -> List[Dict]:
     """(fleet size, cloudlet count, SLO mix) grid; first cell is the
-    headline the BENCH record leads with."""
+    headline the report leads with."""
     if fast:
         return [
             {"n_edges": 1000, "n_cloudlets": 8, "slo_mix": "default",
@@ -81,6 +84,38 @@ def _scenario(cell: Dict, seed: int = 7) -> FleetScenario:
         seed=seed, n_edges=cell["n_edges"],
         n_cloudlets=cell["n_cloudlets"], duration_s=cell["duration_s"],
         slo_classes=SLO_MIXES[cell["slo_mix"]])
+
+
+def _fmt(v) -> str:
+    if isinstance(v, float):
+        return f"{v:.4g}"
+    return str(v)
+
+
+def table(rows: List[Dict], cols: List[str], title: str) -> str:
+    widths = {c: max([len(c)] + [len(_fmt(r[c])) for r in rows])
+              for c in cols}
+    lines = [f"== {title} ==",
+             " | ".join(c.ljust(widths[c]) for c in cols),
+             "-+-".join("-" * widths[c] for c in cols)]
+    lines += [" | ".join(_fmt(r[c]).ljust(widths[c]) for c in cols)
+              for r in rows]
+    return "\n".join(lines)
+
+
+def record(res: Dict) -> Dict:
+    """The flat summary ``--json`` prints: the headline scenario's full
+    rollup (fleet p50/p99, joules/request, deadline attainment, per-tier
+    shed/utilization/queue metrics) plus each cell's summary keys."""
+    rec: Dict = dict(res["headline"])
+    rec["determinism_ok"] = res["determinism_ok"]
+    for row in res["rows"]:
+        k = (f"{row['slo_mix']}_{row['n_edges']}edges"
+             f"_{row['n_cloudlets']}cl")
+        for m in ("deadline_met_frac", "shed_frac", "latency_p99_s",
+                  "cloud_util"):
+            rec[f"{k}_{m}"] = row[m]
+    return rec
 
 
 def run(fast: bool = False) -> Dict:
@@ -154,9 +189,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized grid (2 cells, 30s virtual each)")
     ap.add_argument("--json", action="store_true",
-                    help="write the tracked BENCH_fleet.json perf record")
+                    help="print the summary as JSON on the last line")
     args = ap.parse_args()
     res = run(fast=args.smoke)
-    if args.json or args.smoke:
-        # the CI smoke path owns the tracked record, like cloud_batching
-        print(f"perf record: {write_fleet_record(res)}")
+    if args.json:
+        print(json.dumps(record(res), sort_keys=True))

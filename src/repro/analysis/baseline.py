@@ -18,7 +18,7 @@ exactly. Two properties are enforced, not hoped for:
   fixed findings must leave the baseline with the fix, so the file
   never accretes dead exemptions. Staleness is only decided for entries
   whose ``path`` was actually scanned: a partial run (e.g. the CI step
-  that checks ``benchmarks/fleet_sim.py`` alone) cannot conclude an
+  that checks ``examples/fleet_sim.py`` alone) cannot conclude an
   entry is dead for a file it never analyzed.
 """
 from __future__ import annotations

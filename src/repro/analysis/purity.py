@@ -19,7 +19,7 @@ This checker forbids, lexically:
 
 An **allow marker** — a ``# wall-clock: <why>`` comment with a
 non-empty justification on the offending line — suppresses the finding
-in place; it is how ``benchmarks/fleet_sim.py`` pins its wall-vs-virtual
+in place; it is how ``examples/fleet_sim.py`` pins its wall-vs-virtual
 split (wall seconds are measured for the sweep report but must never
 enter a rollup). Markers without a justification do not suppress.
 """

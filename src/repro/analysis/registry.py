@@ -148,9 +148,9 @@ CONCURRENCY: Dict[str, Tuple] = {
 PURITY_SCOPES: Dict[str, Optional[Tuple[str, ...]]] = {
     "core/collab/channel.py": ("SimChannel",),
     "core/partition/profiles.py": ("LinkTrace",),
-    # the fleet benchmark drives the virtual clock; its two wall-clock
+    # the fleet-sim example drives the virtual clock; its two wall-clock
     # sweep-timing lines are pinned by justified `# wall-clock:` markers
-    "benchmarks/fleet_sim.py": None,
+    "examples/fleet_sim.py": None,
 }
 
 #: directory fragment whose every file is in the purity domain

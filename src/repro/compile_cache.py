@@ -1,11 +1,11 @@
 """The persistent JAX compilation cache of this repository's programs.
 
-``enable()`` is called at the top of a program (``chip_smoke.py``,
-``benchmarks/run.py``, the examples), never as a library module is
-imported. A cache directory is part of the cache's key, so it must not
-move between runs: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads
-it and nothing is set here; otherwise the cache lives in the fixed
-``.jax_cache/`` directory of this checkout (listed in ``.gitignore``).
+``enable()`` is called at the top of a program (``bench/run.py``, the
+examples), never as a library module is imported. A cache directory is
+part of the cache's key, so it must not move between runs: where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it and nothing is set
+here; otherwise the cache lives in the fixed ``.jax_cache/`` directory
+of this checkout (listed in ``.gitignore``).
 """
 from __future__ import annotations
 

@@ -87,10 +87,9 @@ class ModelConfig:
     vocab_pad_to: int = 0          # pad vocab for even sharding (0 = none)
     dtype: str = "bfloat16"
     remat: bool = True
-    # scan_layers=False unrolls the layer stack into straight-line HLO.
-    # Used by the dry-run: XLA's HloCostAnalysis counts a while-loop body
-    # ONCE regardless of trip count, so roofline FLOPs/bytes/collectives
-    # must come from unrolled lowerings (see roofline/analysis.py).
+    # scan_layers=False unrolls the layer stack into straight-line HLO:
+    # XLA's HloCostAnalysis counts a while-loop body ONCE regardless of
+    # trip count, so cost_analysis FLOPs/bytes need unrolled lowerings.
     scan_layers: bool = True
     # unroll the chunked-attention KV-block scan (same cost_analysis reason)
     attn_block_unroll: bool = False

@@ -235,14 +235,10 @@ class DynamicBatcher:
     A frame wider than ``max_batch`` is rejected — the client should have
     chunked it.
 
-    ``invoke_cost(split, bucket_rows)`` — optional hook charged once per
-    cloud call (after the real compute): ``serve_cloud``'s simulated-
-    server mode passes the analytic per-invocation device time here,
-    serialized on the modeled accelerator, so colocated benchmarks
-    measure the engine against the paper's hardware instead of this
-    host's core count. Charged at the padded bucket size — the modeled
-    device executes the padding too, which is what makes padding waste a
-    physical quantity.
+    ``invoke_cost(split, bucket_rows)`` — optional hook called once per
+    cloud call, after the real compute, with the padded bucket size; it
+    runs on the lane's scheduler thread, so a blocking hook holds the
+    lane (how the tests fill a bounded queue).
     """
 
     def __init__(self, bank, policy: BatchingPolicy,

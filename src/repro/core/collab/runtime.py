@@ -579,7 +579,6 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
                 trace: Optional[LinkTrace] = None,
                 batching: Optional[BatchingPolicy] = None,
                 batch_stats: Optional[Dict] = None,
-                simulate_server=None,
                 fault_policy: Optional[FaultPolicy] = None,
                 faults: Optional[FaultInjector] = None,
                 fault_stats: Optional[Dict] = None,
@@ -639,16 +638,6 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
     shuts down; ``on_engine`` is called with the engine once it is built,
     before the server listens, so a caller can read its live counters.
 
-    ``simulate_server`` (a ``ComputeProfile``) charges every cloud
-    invocation the analytic ``batched_server_time`` on that hardware,
-    serialized server-wide — the modeled accelerator executes one batch
-    at a time, like ``CollabRunner``'s ``simulate_compute`` this
-    container stands in for. Colocated benchmarks use it to measure the
-    batching engine against the paper's 3090 rather than against this
-    host's core count (on which N real batch-1 calls may parallelize in
-    ways the target device cannot). Real compute still runs first, so
-    logits and bit-identity are unaffected.
-
     Fault tolerance: an edge whose HELLO advertises ``CAP_CRC`` gets
     sealed (CRC32 + sequence-number) data frames both ways — a
     corrupted request surfaces as ``FrameIntegrityError`` and closes
@@ -680,21 +669,7 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
     instead of stalling the connection.
     """
     bank = SplitFnBank(params, cfg, masks, compact, quant=quant)
-    charge = None
-    if simulate_server is not None:
-        from repro.core.partition.latency_model import (
-            batched_server_time, cnn_layer_costs, compacted_cnn_layer_costs)
-        sim_costs = (compacted_cnn_layer_costs(cfg, masks)
-                     if compact else cnn_layer_costs(cfg, masks))
-        device_lock = threading.Lock()
-
-        def charge(c: int, rows: int) -> None:
-            dt = batched_server_time(sim_costs, c, simulate_server, rows)
-            with device_lock:            # one modeled accelerator
-                time.sleep(dt)
-
-    engine = (DynamicBatcher(bank, batching, invoke_cost=charge)
-              if batching else None)
+    engine = DynamicBatcher(bank, batching) if batching else None
     if engine is not None and on_engine is not None:
         on_engine(engine)
     warm_splits = list(resplit_candidates or ())
@@ -887,8 +862,6 @@ def serve_cloud(params, cfg: CNNConfig, split: int, port: int,
                     logits = np.asarray(
                         cloud_fn(arr) if cloud_fn is not None
                         else arr)  # c=N: edge sent the logits
-                    if charge is not None and cloud_fn is not None:
-                        charge(cur_split, rows)
                     _respond_data(encode_tensor(logits), seq)
                 served += 1
                 rec["claimed"] = True

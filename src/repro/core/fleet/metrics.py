@@ -1,7 +1,7 @@
 """Fleet metrics: per-request records in, one flat rollup out.
 
-The rollup is the BENCH_fleet.json payload — every key unit-suffixed
-per the bench-record convention, every value derived from the virtual
+The rollup is the fleet-sim report — every key unit-suffixed per the
+repo's record convention, every value derived from the virtual
 clock and the analytic models. No wall-clock second ever lands here:
 two runs of the same scenario seed must produce byte-identical
 rollups, and the determinism regression test holds us to it.
@@ -62,7 +62,7 @@ class FleetMetrics:
     def rollup(self, cloudlet_stats: List[TierStats],
                cloud_stats: TierStats,
                exhausted_edges: int = 0) -> Dict[str, float]:
-        """The flat, unit-suffixed summary dict for BENCH_fleet.json.
+        """The flat, unit-suffixed summary dict of one scenario.
 
         Served = collab + degraded-edge; deadline attainment is judged
         over *arrivals* (a shed request is a missed deadline — hiding

@@ -61,8 +61,8 @@ class _Request:
 class FleetSimulator:
     """Drives one ``FleetScenario`` to completion and rolls up metrics.
 
-    ``run()`` returns the flat BENCH_fleet rollup dict. The network
-    defaults to the paper's AlexNet/PlantVillage configuration (the
+    ``run()`` returns the flat rollup dict (``FleetMetrics.rollup``).
+    The network defaults to the paper's AlexNet/PlantVillage configuration (the
     same cost table every other subsystem prices), overridable for
     tests via ``costs``/``input_bytes``.
     """

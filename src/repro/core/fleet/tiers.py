@@ -9,9 +9,9 @@ batch will run (requests of different splits never fuse — their
 tensors have different shapes), a batching window while the server is
 idle, padding to the ``BatchingPolicy``'s bucket shapes, and ONE
 modeled invocation per fused batch priced by
-``latency_model.batched_segment_time`` — the same single formula the
-measured batching engine charges through ``simulate_server``, so fleet
-numbers and socket-bench numbers can never drift apart.
+``latency_model.batched_segment_time`` — the same single formula
+``batched_server_time`` prices a fused cloud batch with, so the
+fleet's tiers and the split model can never drift apart.
 
 Hardware defaults mirror the calibrated registry: a cloudlet is the
 Jetson-class aggregation box (``profiles.CLOUDLET_SERVER``), the cloud

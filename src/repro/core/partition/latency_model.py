@@ -360,13 +360,12 @@ def split_latency(costs: Sequence[LayerCost], c: int,
     concrete deployment. Compute-side memory traffic is unaffected.
 
     **T_TX is uplink-only by default**: it charges the feature tensor
-    (device -> server) plus ONE RTT, matching the paper's Eq. 5 and every
-    comparison table in ``benchmarks/``. The socket path is actually
-    request/response — logits come back — so ``round_trip=True`` adds the
-    return payload (the final layer's output bytes) and a second RTT for
-    deployments where the downlink is not negligible. ``tx_bytes`` in the
-    returned row stays uplink-only either way (it is what the runtimes
-    report as transmitted feature bytes)."""
+    (device -> server) plus ONE RTT, matching the paper's Eq. 5. The
+    socket path is actually request/response — logits come back — so
+    ``round_trip=True`` adds the return payload (the final layer's output
+    bytes) and a second RTT for deployments where the downlink is not
+    negligible. ``tx_bytes`` in the returned row stays uplink-only either
+    way (it is what the runtimes report as transmitted feature bytes)."""
     n = len(costs)
     assert 0 <= c <= n
 

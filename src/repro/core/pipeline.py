@@ -1,4 +1,4 @@
-"""The paper's end-to-end pipeline, reusable by examples/ and benchmarks/:
+"""The paper's end-to-end pipeline, reusable by examples/ and tests/:
 
   train CNN -> DDPG pruning search -> fine-tune -> greedy split ->
   compact -> deploy.

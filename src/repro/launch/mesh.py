@@ -1,14 +1,7 @@
-"""Production mesh construction.
-
-Single pod : (16, 16)      axes ("data", "model")        = 256 chips
-Multi-pod  : (2, 16, 16)   axes ("pod", "data", "model") = 512 chips
+"""Host mesh construction.
 
 A FUNCTION, not a module constant, so importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before first jax use).
-
-The "pod" axis doubles as the paper's edge/cloud boundary in the Tier-B
-split-inference runtime (DESIGN.md §2): pod 0 = edge tier, pod 1 = cloud
-tier, and the split activation crosses pods via collective-permute.
+jax device state.
 """
 from __future__ import annotations
 
@@ -16,19 +9,10 @@ import jax
 from jax.sharding import AxisType
 
 
-def _auto_mesh(shape, axes):
-    """Auto (GSPMD-propagated) axes: the model code places activations
-    with ``maybe_constrain`` hints, which only Auto axes accept."""
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
-
-
 def make_host_mesh():
-    """Whatever this host actually has (smoke tests / examples): 1 device."""
+    """Whatever this host actually has (smoke tests / examples): 1 device.
+    Auto (GSPMD-propagated) axes: the model code places activations with
+    ``maybe_constrain`` hints, which only Auto axes accept."""
     n = len(jax.devices())
-    return _auto_mesh((1, n), ("data", "model"))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

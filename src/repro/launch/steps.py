@@ -1,8 +1,5 @@
-"""Jittable step functions (train / prefill / decode) shared by the
-launchers, the dry-run, and the benchmarks."""
+"""The jittable transformer train step."""
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,15 +57,3 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer, masks=None,
 
     return train_step
 
-
-def make_prefill_step(cfg: ModelConfig, max_len: Optional[int] = None,
-                      masks=None):
-    def prefill_step(params, batch):
-        return tr.prefill(params, cfg, batch, max_len=max_len, masks=masks)
-    return prefill_step
-
-
-def make_decode_step(cfg: ModelConfig, masks=None):
-    def decode_step(params, cache, tokens):
-        return tr.decode_step(params, cfg, cache, tokens, masks=masks)
-    return decode_step

@@ -77,9 +77,10 @@ def hybrid_split(cfg: ModelConfig, count: int) -> Tuple[int, int]:
 def _maybe_scan(cfg, body, carry, xs):
     """lax.scan, or an unrolled python loop when cfg.scan_layers=False.
 
-    Unrolling exists for the dry-run: XLA's HloCostAnalysis counts a
-    while-loop body once regardless of trip count, so roofline numbers must
-    come from straight-line HLO. Results are identical either way (tested).
+    Unrolling exists for cost analysis: XLA's HloCostAnalysis counts a
+    while-loop body once regardless of trip count, so FLOP/byte counts
+    must come from straight-line HLO. Results are identical either way
+    (tested).
     """
     if cfg.scan_layers:
         return jax.lax.scan(body, carry, xs)

@@ -1,180 +1,12 @@
-"""Roofline-term extraction from a compiled dry-run artifact.
-
-    compute term    = HLO_FLOPs_global / (chips x peak_FLOP/s)
-    memory term     = HLO_bytes_global / (chips x HBM_bw)
-    collective term = collective_bytes_global / (chips x link_bw)
-
-UNITS: ``compiled.cost_analysis()`` on an SPMD-partitioned module reports
-the PER-DEVICE program (XLA compiles one replica); global = per-device x
-chips, so the assignment's formulas reduce to per-device quantity / per-chip
-throughput — which is how they are computed here.
-
-Collective bytes are NOT in cost_analysis: we parse the post-SPMD optimized
-HLO and sum the operand sizes of every all-gather / all-reduce /
-reduce-scatter / all-to-all / collective-permute. Post-SPMD operand shapes
-are per-device, so the sum is per-chip traffic; dividing by the per-chip
-link bandwidth gives the collective term. (Ring all-reduce actually moves
-~2x its operand bytes per chip; operand-size is therefore a <=2x-optimistic
-proxy, uniform across configs, which is what the hillclimb compares.)
+"""Analytic roofline of the quantized edge kernel on an edge
+``ComputeProfile``: per-layer compute vs weight-streaming time, and the
+check that the quantized GEMM layers sit at the memory-bound ceiling.
 """
 from __future__ import annotations
 
-import re
-from dataclasses import dataclass, field
-from typing import Dict, Optional
-
-from repro.roofline import hw
-
-_DTYPE_BYTES = {
-    "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
-    "f8e5m2": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
-    "c128": 16, "token": 0, "opaque": 0,
-}
-
-_SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\]")
-_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?(%[\w\.\-]+)\s*=\s*(.+?)\s+"
-                     r"([\w\-]+)\(", re.M)
-COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter",
-                  "all-to-all", "collective-permute")
+from typing import Optional
 
 
-def shape_bytes(type_str: str) -> int:
-    """Bytes of an HLO result type (handles tuples by summing)."""
-    total = 0
-    for m in _SHAPE_RE.finditer(type_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-@dataclass
-class CollectiveStats:
-    bytes_by_op: Dict[str, int] = field(default_factory=dict)
-    count_by_op: Dict[str, int] = field(default_factory=dict)
-
-    @property
-    def total_bytes(self) -> int:
-        return sum(self.bytes_by_op.values())
-
-
-def parse_collectives(hlo_text: str) -> CollectiveStats:
-    """Sum operand bytes per collective op kind from optimized HLO."""
-    # 1st pass: result bytes of every definition
-    sizes: Dict[str, int] = {}
-    for m in _DEF_RE.finditer(hlo_text):
-        sizes[m.group(1)] = shape_bytes(m.group(2))
-    stats = CollectiveStats()
-    for m in _DEF_RE.finditer(hlo_text):
-        op = m.group(3)
-        base = None
-        for c in COLLECTIVE_OPS:
-            if op == c or op.startswith(c + "-start"):
-                base = c
-                break
-        if base is None:
-            continue
-        # operand list: text after '(' up to matching ')'
-        line_start = m.end()
-        rest = hlo_text[line_start:hlo_text.find("\n", line_start)]
-        depth = 1
-        args = ""
-        for ch in rest:
-            if ch == "(":
-                depth += 1
-            elif ch == ")":
-                depth -= 1
-                if depth == 0:
-                    break
-            args += ch
-        nbytes = 0
-        for op_m in re.finditer(r"%[\w\.\-]+", args):
-            nbytes += sizes.get(op_m.group(0), 0)
-        stats.bytes_by_op[base] = stats.bytes_by_op.get(base, 0) + nbytes
-        stats.count_by_op[base] = stats.count_by_op.get(base, 0) + 1
-    return stats
-
-
-@dataclass
-class RooflineTerms:
-    flops: float                 # PER-DEVICE HLO flops (cost_analysis)
-    hbm_bytes: float             # PER-DEVICE HLO bytes accessed
-    collective_bytes: float      # per-chip collective operand bytes
-    chips: int
-
-    @property
-    def flops_global(self) -> float:
-        return self.flops * self.chips
-
-    @property
-    def hbm_bytes_global(self) -> float:
-        return self.hbm_bytes * self.chips
-
-    @property
-    def t_compute(self) -> float:
-        # global/(chips*peak) == per-device/peak
-        return self.flops / hw.PEAK_FLOPS_BF16
-
-    @property
-    def t_memory(self) -> float:
-        return self.hbm_bytes / hw.HBM_BW
-
-    @property
-    def t_collective(self) -> float:
-        return self.collective_bytes / hw.ICI_BW_PER_LINK
-
-    @property
-    def dominant(self) -> str:
-        terms = {"compute": self.t_compute, "memory": self.t_memory,
-                 "collective": self.t_collective}
-        return max(terms, key=terms.get)
-
-    def as_dict(self) -> Dict:
-        return {
-            "flops": self.flops, "hbm_bytes": self.hbm_bytes,
-            "flops_global": self.flops_global,
-            "hbm_bytes_global": self.hbm_bytes_global,
-            "collective_bytes_per_chip": self.collective_bytes,
-            "chips": self.chips,
-            "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
-            "t_collective_s": self.t_collective, "dominant": self.dominant,
-        }
-
-
-def model_flops(cfg, shape_name: str, n_params_active: Optional[int] = None,
-                n_params: Optional[int] = None) -> float:
-    """MODEL_FLOPS = 6 * N * D (dense) / 6 * N_active * D (MoE); decode uses
-    D = tokens generated this step (=batch)."""
-    from repro.launch.specs import SHAPES, mode_of
-    S, B = SHAPES[shape_name]
-    mode = mode_of(shape_name)
-    N = n_params_active if n_params_active is not None else n_params
-    D = B * S if mode != "decode" else B
-    factor = 6.0 if mode == "train" else 2.0
-    return factor * float(N) * float(D)
-
-
-def terms_from_compiled(compiled, chips: int,
-                        hlo_text: Optional[str] = None) -> RooflineTerms:
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):           # older jax returns [dict]
-        cost = cost[0]
-    flops = float(cost.get("flops", 0.0))
-    nbytes = float(cost.get("bytes accessed", 0.0))
-    text = hlo_text if hlo_text is not None else compiled.as_text()
-    coll = parse_collectives(text)
-    return RooflineTerms(flops, nbytes, float(coll.total_bytes), chips), coll
-
-
-# ---------------------------------------------------------------------------
-# quantized edge-kernel roofline (the MCU/Pi memory-bound ceiling)
-# ---------------------------------------------------------------------------
 def quant_edge_roofline(cfg, masks, profile,
                         weight_bits: Optional[int] = 8) -> list:
     """Per-layer roofline of the quantized kernel edge path on an edge

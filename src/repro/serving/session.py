@@ -459,7 +459,6 @@ def serve(plan: DeploymentPlan, *, port: Optional[int] = None,
           verify: bool = True,
           trace: Optional[LinkTrace] = None,
           batch_stats: Optional[Dict] = None,
-          simulate_server=None,
           faults: Optional[FaultInjector] = None,
           fault_stats: Optional[Dict] = None,
           die: Optional[threading.Event] = None,
@@ -477,10 +476,6 @@ def serve(plan: DeploymentPlan, *, port: Optional[int] = None,
     per-lane accounting (fill rate, padding waste) on shutdown, or
     ``on_engine`` to be handed the engine itself before the server
     listens (``CloudServer.lane_stats`` reads it live).
-    ``simulate_server`` (a ``ComputeProfile``) additionally charges each
-    cloud invocation its analytic device time on that hardware,
-    serialized server-wide (see ``serve_cloud``) — the benchmark knob for
-    measuring the engine against the paper's 3090 on this container.
 
     A plan with a ``faults`` section arms the server's recovery side:
     sealed (CRC + sequence) frames are negotiated per connection via the
@@ -506,7 +501,7 @@ def serve(plan: DeploymentPlan, *, port: Optional[int] = None,
                 resplit_candidates=(plan.adaptive.candidates
                                     if plan.adaptive else None),
                 trace=trace, batching=plan.batching,
-                batch_stats=batch_stats, simulate_server=simulate_server,
+                batch_stats=batch_stats,
                 fault_policy=plan.faults, faults=faults,
                 fault_stats=fault_stats, die=die, drain=drain,
                 quant=plan.quant, on_engine=on_engine)
@@ -524,7 +519,6 @@ class CloudServer:
                  max_requests: Optional[int] = None,
                  max_clients: Optional[int] = None, verify: bool = True,
                  trace: Optional[LinkTrace] = None,
-                 simulate_server=None,
                  faults: Optional[FaultInjector] = None):
         self.plan = plan
         #: per-lane dynamic-batching accounting (filled on shutdown when
@@ -546,8 +540,7 @@ class CloudServer:
                 serve(plan, port=port, host=host, max_requests=max_requests,
                       max_clients=max_clients, ready=ready,
                       stop=self._stop, verify=verify, trace=trace,
-                      batch_stats=self.batch_stats,
-                      simulate_server=simulate_server, faults=faults,
+                      batch_stats=self.batch_stats, faults=faults,
                       fault_stats=self.fault_stats, die=self._die,
                       drain=self._drain, on_engine=self._adopt_engine)
             except Exception as e:                   # noqa: BLE001
@@ -636,8 +629,7 @@ class CloudFleet:
     """
 
     def __init__(self, plan: DeploymentPlan, *, verify: bool = True,
-                 max_clients: Optional[int] = None,
-                 simulate_server=None):
+                 max_clients: Optional[int] = None):
         if plan.routing is None or not plan.routing.ports:
             raise ValueError(
                 "CloudFleet needs a plan with a routing section "
@@ -645,7 +637,6 @@ class CloudFleet:
         self.plan = plan
         self._verify = verify
         self._max_clients = max_clients
-        self._simulate_server = simulate_server
         self._lock = threading.Lock()
         self._servers: Dict[int, CloudServer] = {}
         for p in plan.routing.ports:
@@ -654,7 +645,7 @@ class CloudFleet:
     def _spawn(self, port: int) -> CloudServer:
         return CloudServer(
             self.plan, port=port, max_clients=self._max_clients,
-            verify=self._verify, simulate_server=self._simulate_server)
+            verify=self._verify)
 
     @property
     def ports(self) -> tuple:

@@ -1,5 +1,5 @@
-"""Sharding planner: PartitionSpec pytrees for params, optimizer state,
-batches, and caches, per (config, mesh).
+"""Sharding planner: PartitionSpec pytrees for params, batches, and
+caches, per (config, mesh).
 
 Strategy (baseline; §Perf iterates on it):
   * 2-D weight sharding — every large matmul weight shards its d_model-side
@@ -117,23 +117,6 @@ def param_specs(params, cfg: ModelConfig, mesh: Mesh,
         return P(*fixed)
 
     return jax.tree_util.tree_map_with_path(spec_for, params)
-
-
-def opt_state_specs(opt_state, pspecs):
-    """Optimizer moments mirror the param specs; counters replicate."""
-    def match(path, leaf):
-        keys = list(path_keys(path))
-        if keys and keys[0] in ("m", "v", "mom"):
-            sub = keys[1:]
-            node = pspecs
-            for k in sub:
-                if isinstance(node, (list, tuple)):
-                    node = node[int(k)]
-                else:
-                    node = node[k]
-            return node
-        return P()
-    return jax.tree_util.tree_map_with_path(match, opt_state)
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches see
-the host's single CPU device; only launch/dryrun.py forces 512 devices."""
+the host's single CPU device."""
 from __future__ import annotations
 
 import jax

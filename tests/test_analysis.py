@@ -9,7 +9,7 @@ digest-fold mismatch, pack/unpack drift) each appear as an explicit
 fixture. The ``analysis``-marked tests at the bottom run the real gate
 over the repo: ``src/`` must be green against the checked-in baseline,
 ``core/fleet/`` must be green *without* any baseline, and
-``benchmarks/fleet_sim.py``'s wall-vs-virtual timing split must stay
+``examples/fleet_sim.py``'s wall-vs-virtual timing split must stay
 pinned to its two justified allow-marker lines.
 """
 from __future__ import annotations
@@ -424,11 +424,11 @@ def test_partial_scan_cannot_declare_staleness():
 
 
 @pytest.mark.analysis
-def test_fleet_benchmark_partial_run_with_real_baseline():
-    """The CI step analyzes benchmarks/fleet_sim.py alone against the
+def test_fleet_sim_partial_run_with_real_baseline():
+    """The CI step analyzes examples/fleet_sim.py alone against the
     checked-in baseline: the SimChannel entry's file is out of scope
     for that run, so it must not surface as a stale suppression."""
-    report = run_analysis([os.path.join(REPO, "benchmarks",
+    report = run_analysis([os.path.join(REPO, "examples",
                                         "fleet_sim.py")],
                           baseline_path=BASELINE)
     assert report.ok, report.render()
@@ -464,11 +464,11 @@ def test_fleet_tree_pure_without_baseline():
 
 
 @pytest.mark.analysis
-def test_fleet_benchmark_wall_clock_pinned():
-    """benchmarks/fleet_sim.py: exactly its two sweep-timing lines carry
+def test_fleet_sim_wall_clock_pinned():
+    """examples/fleet_sim.py: exactly its two sweep-timing lines carry
     justified wall-clock markers; everything else is virtual-clock
     pure. Moving a wall read elsewhere breaks this test."""
-    path = os.path.join(REPO, "benchmarks", "fleet_sim.py")
+    path = os.path.join(REPO, "examples", "fleet_sim.py")
     report = run_analysis([path], entries=[])
     assert report.ok, report.render()
     with open(path) as f:

@@ -341,27 +341,42 @@ def test_saturated_lane_sheds_busy_instead_of_stalling(plan_setup):
     plan = make_plan(plan_setup, port,
                      batching=BatchingPolicy(max_batch=1, max_wait_ms=1.0,
                                              max_queue=1))
-    # a modeled accelerator with a fat per-invocation constant holds the
-    # lane long enough that back-to-back raw frames overflow the bound
-    molasses = ComputeProfile("molasses", flops_per_s=1e12, mem_bw=1e12,
-                              overhead_s=0.4)
     cfg, params, masks, _, _ = plan_setup
     bank = SplitFnBank(params, cfg, masks, True)
     edge_fn, _, _ = bank.get(SPLIT)
     payload = encode_feature(np.asarray(edge_fn(jax.numpy.asarray(x))),
                              codec="fp32")
-    srv = serving.CloudServer(plan, max_clients=1, verify=False,
-                              simulate_server=molasses)
+    started, gate = threading.Event(), threading.Event()
+
+    def hold(c, rows):
+        started.set()
+        gate.wait(10.0)
+
+    srv = serving.CloudServer(plan, max_clients=1, verify=False)
+    # the live engine (adopted before the server listens) holds its
+    # first batch, so back-to-back raw frames overflow the bounded lane
+    srv._engine.invoke_cost = hold
     try:
         with socket.create_connection(("127.0.0.1", port), timeout=10) as s:
             s.settimeout(10.0)
             for _ in range(3):               # burst: no reads in between
                 s.sendall(struct.pack("<Q", len(payload)) + payload)
+            # release the lane only once frames 2 and 3 have each been
+            # queued behind the held batch or shed
+            deadline = time.monotonic() + 10.0
+            while time.monotonic() < deadline and not (
+                    started.is_set()
+                    and srv.fault_stats.get("busy_shed", 0) + sum(
+                        r["pending"] for r in srv.lane_stats().values())
+                    >= 2):
+                time.sleep(0.005)
+            gate.set()
             replies = []
             for _ in range(3):
                 (n,) = struct.unpack("<Q", _read_exact(s, 8))
                 replies.append(_read_exact(s, n))
     finally:
+        gate.set()
         srv.stop()
     busy = [b for b in replies if is_busy(b)]
     served = [np.asarray(decode_tensor(b)[0])

@@ -1,6 +1,6 @@
 """Fleet simulator tests: virtual clock, seeded population, admission,
 tier batching, scenario serialization, and the determinism regression
-the BENCH_fleet record depends on."""
+the fleet-sim report depends on."""
 import random
 
 import pytest
@@ -240,7 +240,7 @@ def test_fleet_run_conserves_arrivals_and_uses_every_route():
 
 
 def test_fleet_same_seed_rollups_are_bit_identical():
-    # the determinism regression BENCH_fleet.json depends on: same
+    # the determinism regression the fleet-sim report depends on: same
     # scenario seed -> byte-identical metrics, run to run
     sc = FleetScenario(name="det", seed=21, n_edges=250, n_cloudlets=3,
                        duration_s=15.0)
